@@ -79,7 +79,7 @@ def roofline_table() -> List[str]:
                 rf = r["roofline"]
                 terms = {"compute": rf["compute_s"], "memory": mem_an, "collective": rf["collective_s"]}
                 bn = max(terms, key=terms.get)
-                ideal = rf["model_flops_per_device"] / hw.PEAK_FLOPS_BF16
+                ideal = rf["model_flops_per_device"] / hw.MODELLED_PEAK_FLOPS_BF16
                 frac = ideal / max(max(terms.values()), 1e-12)
                 out.append(
                     f"| {arch} | {shape_name} | {rf['compute_s']:.3f} | {mem_an:.3f} | "
@@ -91,11 +91,11 @@ def roofline_table() -> List[str]:
                 from repro.roofline.analysis import model_flops_for_cell
 
                 mf = model_flops_for_cell(cfg, shape) / 256
-                comp = mf / hw.PEAK_FLOPS_BF16 / 0.4  # assume useful ratio ~0.4
+                comp = mf / hw.MODELLED_PEAK_FLOPS_BF16 / 0.4  # assume useful ratio ~0.4
                 out.append(
                     f"| {arch} | {shape_name} | ~{comp:.3f}* | {mem_an:.3f} | n/a | n/a | "
                     f"{'memory' if mem_an > comp else 'compute'}* | n/a | "
-                    f"{(mf/hw.PEAK_FLOPS_BF16)/max(mem_an, comp):.3f}* |"
+                    f"{(mf/hw.MODELLED_PEAK_FLOPS_BF16)/max(mem_an, comp):.3f}* |"
                 )
     out.append("")
     out.append("`*` analytic-only cells (unrolled SSD-prefill HLO impractical to compile on the CPU container; see dryrun policy note).")

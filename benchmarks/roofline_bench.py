@@ -61,7 +61,7 @@ def run() -> List[Row]:
                 useful_ratio=round(rf["useful_ratio"], 3),
                 collective_counts=rf["collective_counts"],
                 roofline_frac=round(
-                    max(rf["model_flops_per_device"] / hw.PEAK_FLOPS_BF16, 1e-12)
+                    max(rf["model_flops_per_device"] / hw.MODELLED_PEAK_FLOPS_BF16, 1e-12)
                     / max(max(terms.values()), 1e-12),
                     4,
                 ),

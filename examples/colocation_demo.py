@@ -41,7 +41,8 @@ def make_job(arch: str, seed: int) -> ColocatedJob:
 
 def main() -> None:
     jobs = [make_job("minitron-8b", 0), make_job("mamba2-370m", 1)]
-    profiler = EarlyStageProfiler(flops_per_step={j.name: 1e9 for j in jobs})
+    # step times only: no FLOPs counts, so no duty cycle and no device peak
+    profiler = EarlyStageProfiler(flops_per_step={})
 
     stepper = TemporalStepper(jobs)
     steps = 1 if FAST else 3

@@ -72,7 +72,7 @@ def analytic_job(
         solo_step_s=solo_step_s,
         duty_cycle_pct=profile.gpu_util,
         mem_util_pct=profile.mem_util,
-        flops_per_step=profile.gpu_util / 100.0 * solo_step_s * hw.PEAK_FLOPS_BF16,
+        flops_per_step=profile.gpu_util / 100.0 * solo_step_s * hw.MODELLED_PEAK_FLOPS_BF16,
     )
     return ColocatedJob(
         name=profile.name,

@@ -14,10 +14,10 @@ node-failure recovery path.
 
 from __future__ import annotations
 
+import io
 import json
 import os
 import shutil
-import tempfile
 import threading
 from typing import Any, Dict, Optional, Tuple
 
@@ -140,10 +140,7 @@ def restore_checkpoint(
     if not os.path.exists(npz_path) and os.path.exists(npz_path + ".zst"):
         with open(npz_path + ".zst", "rb") as f:
             blob = zstd.ZstdDecompressor().decompress(f.read())
-        with tempfile.NamedTemporaryFile(suffix=".npz", delete=False) as tf:
-            tf.write(blob)
-            tmpname = tf.name
-        data = np.load(tmpname)
+        data = np.load(io.BytesIO(blob))
     else:
         data = np.load(npz_path)
     with open(os.path.join(path, "tree.json")) as f:

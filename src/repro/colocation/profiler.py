@@ -2,10 +2,11 @@
 
 Measures per-job step time and an MFU-style duty cycle during the first
 epoch(s) of (co-located) execution; the measurements feed EaCO's history H.
-On TPU the duty cycle comes from libtpu telemetry; in this repo it is
-derived from the dry-run cost model: duty = step_FLOPs / (step_time x
-peak_FLOPs) (DESIGN.md §2 — the conservative "utilization" metric the
-paper argues for, not occupancy).
+The duty cycle is duty = step_FLOPs / (step_time x peak_FLOPs) (the
+conservative "utilization" metric the paper argues for, not occupancy).
+Real steps are divided by the published peak of the device they ran on;
+``AnalyticBundle`` steps, which run on a virtual clock, by the modelled v5e
+peak.
 """
 
 from __future__ import annotations
@@ -13,9 +14,10 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional
 
+import jax
 import numpy as np
 
-from repro.colocation.stepper import ColocatedJob, TemporalStepper
+from repro.colocation.stepper import AnalyticBundle, TemporalStepper
 from repro.roofline import hw
 
 
@@ -31,13 +33,18 @@ class EarlyStageProfiler:
     """Observe co-located jobs for ``observe_steps`` steps; compare against
     solo baselines to produce measured inflation factors."""
 
-    def __init__(self, flops_per_step: Dict[str, float], peak_flops: float = hw.PEAK_FLOPS_BF16):
+    def __init__(
+        self, flops_per_step: Dict[str, float], peak_flops: Optional[float] = None
+    ):
+        """``peak_flops`` None: the published peak of the device the steps
+        run on, looked up when a duty cycle is first computed (an unknown
+        device kind raises).  Jobs without a FLOPs count get duty 0."""
         self.flops_per_step = flops_per_step
         self.peak_flops = peak_flops
         self.solo_step_s: Dict[str, float] = {}
 
     @classmethod
-    def for_stepper(cls, stepper: TemporalStepper, peak_flops: float = hw.PEAK_FLOPS_BF16):
+    def for_stepper(cls, stepper: TemporalStepper):
         """Build a profiler whose FLOPs table comes from the jobs' own
         bundles (``AnalyticBundle.flops_per_step`` in dry-run calibration;
         0.0 — duty reported as 0 — for bundles that don't carry a count)."""
@@ -45,7 +52,8 @@ class EarlyStageProfiler:
             j.name: float(getattr(j.bundle, "flops_per_step", 0.0) or 0.0)
             for j in stepper.jobs
         }
-        return cls(flops, peak_flops)
+        analytic = all(isinstance(j.bundle, AnalyticBundle) for j in stepper.jobs)
+        return cls(flops, hw.MODELLED_PEAK_FLOPS_BF16 if analytic else None)
 
     def profile_solo(self, stepper: TemporalStepper, steps: int = 3) -> Dict[str, Observation]:
         """Profile each job alone (exclusive baseline)."""
@@ -83,6 +91,8 @@ class EarlyStageProfiler:
 
     def _duty(self, name: str, step_s: float) -> float:
         f = self.flops_per_step.get(name, 0.0)
-        if step_s <= 0:
+        if step_s <= 0 or f <= 0:
             return 0.0
+        if self.peak_flops is None:
+            self.peak_flops = hw.peak_flops_bf16(jax.devices()[0].device_kind)
         return min(100.0, 100.0 * f / (step_s * self.peak_flops))

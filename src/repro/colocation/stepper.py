@@ -17,6 +17,7 @@ last epoch snapshot — the scheduler can re-place it on another mesh.
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -108,7 +109,7 @@ class TemporalStepper:
     """Round-robin step interleaving of co-located jobs on one mesh."""
 
     def __init__(self, jobs: List[ColocatedJob], seed: int = 0):
-        self.jobs = jobs
+        self.jobs = list(jobs)  # evict() must not shrink the caller's list
         self._ckpt: Dict[str, AsyncCheckpointer] = {}
         for i, job in enumerate(jobs):
             if job.params is None:
@@ -141,11 +142,15 @@ class TemporalStepper:
             else:
                 batch = self._make_batch(job)
                 t0 = time.perf_counter()
-                job.params, job.opt_state, m = job.bundle.step_fn(
-                    job.params, job.opt_state, batch
+                job.params, job.opt_state, m = jax.block_until_ready(
+                    job.bundle.step_fn(job.params, job.opt_state, batch)
                 )
-                loss = float(m["loss"])  # blocks until the step finishes
                 dt = time.perf_counter() - t0
+                loss = float(m["loss"])
+                if not math.isfinite(loss):
+                    raise FloatingPointError(
+                        f"{job.name}: non-finite loss {loss} at step {job.step + 1}"
+                    )
             job.step += 1
             job.step_times.append(dt)
             job.losses.append(loss)

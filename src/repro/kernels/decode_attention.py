@@ -7,6 +7,16 @@ each program handles all ``rep`` grouped q-heads of one kv head (loads the
 kv block once for the whole group — the GQA bandwidth win), with the kv
 axis innermost/sequential.
 
+The kernel reads the cache head-major, ``(B, Hkv, S, D)``, so that each block
+is a ``(bk, D)`` tile: the TPU compiler requires the last two block dims to
+be tiled (multiples of 8 and 128) or whole, which a ``(1, D)`` slice of the
+``(Hkv, D)`` trailing dims of the ``(B, S, Hkv, D)`` cache is not.  The
+wrapper still takes the sequence-major cache and transposes it on every
+call: a read and a write of the whole cache before the kernel reads it,
+about three times the kernel's own HBM traffic.  The first change when a
+model wires this kernel in is to keep that model's cache head-major and
+make ``(B, Hkv, S, D)`` the wrapper's contract.
+
 The valid cache length arrives via scalar prefetch (SMEM) so block masking
 costs no VMEM traffic; blocks beyond ``valid_len`` are skipped entirely
 (``pl.when``), which matters for partially-filled caches.
@@ -28,7 +38,7 @@ NEG_INF = -1e30
 def _kernel(
     valid_ref,  # SMEM (1,) int32 — scalar prefetch
     q_ref,  # (1, rep, d)
-    k_ref, v_ref,  # (1, bk, 1, d)
+    k_ref, v_ref,  # (1, 1, bk, d)
     o_ref,  # (1, rep, d)
     m_ref, l_ref, acc_ref,  # scratch (rep, 1), (rep, 1), (rep, d)
     *,
@@ -49,7 +59,7 @@ def _kernel(
     @pl.when(k_start < valid)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32)  # (rep, d)
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (bk, d)
+        k = k_ref[0, 0].astype(jnp.float32)  # (bk, d)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (rep, bk)
@@ -61,7 +71,7 @@ def _kernel(
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-        vblk = v_ref[0, :, 0].astype(jnp.float32)  # (bk, d)
+        vblk = v_ref[0, 0].astype(jnp.float32)  # (bk, d)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, vblk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -94,14 +104,16 @@ def decode_attention(
     # group q-heads by kv head: (B, Hkv, rep, D)
     qg = q.reshape(B, Hkv, rep, D)
     valid = jnp.asarray(valid_len, jnp.int32).reshape((1,))
+    k = jnp.swapaxes(k, 1, 2)  # (B, Hkv, S, D)
+    v = jnp.swapaxes(v, 1, 2)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B * Hkv, nk),
         in_specs=[
             pl.BlockSpec((1, 1, rep, D), lambda bh, ki, valid: (bh // Hkv, bh % Hkv, 0, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda bh, ki, valid: (bh // Hkv, ki, bh % Hkv, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda bh, ki, valid: (bh // Hkv, ki, bh % Hkv, 0)),
+            pl.BlockSpec((1, 1, bk, D), lambda bh, ki, valid: (bh // Hkv, bh % Hkv, ki, 0)),
+            pl.BlockSpec((1, 1, bk, D), lambda bh, ki, valid: (bh // Hkv, bh % Hkv, ki, 0)),
         ],
         out_specs=pl.BlockSpec(
             (1, 1, rep, D), lambda bh, ki, valid: (bh // Hkv, bh % Hkv, 0, 0)
@@ -118,5 +130,5 @@ def decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, D), q.dtype),
         interpret=interpret,
-    )(valid, qg.reshape(B, Hkv, rep, D), k, v)
+    )(valid, qg, k, v)
     return out.reshape(B, H, D)

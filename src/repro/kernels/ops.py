@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
 ``backend`` selects the execution path:
-  * "pallas"    — the TPU kernels (on CPU only valid with interpret=True),
-  * "interpret" — Pallas interpret mode (CPU correctness testing),
+  * "pallas"    — the TPU kernels (the default; nothing here falls back
+                  when the device is not a TPU),
+  * "interpret" — Pallas interpret mode (CPU callers pass it explicitly),
   * "xla"       — the pure-jnp production fallback in ``repro.models`` /
                   ``repro.kernels.ref`` (what the dry-run lowers).
 """
@@ -21,7 +22,7 @@ from repro.kernels.flash_attention import flash_attention as _flash_pallas
 from repro.kernels.rmsnorm import rmsnorm as _rmsnorm_pallas
 from repro.kernels.ssd_scan import ssd_scan as _ssd_pallas
 
-DEFAULT_BACKEND = "interpret" if jax.default_backend() == "cpu" else "pallas"
+DEFAULT_BACKEND = "pallas"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "backend"))

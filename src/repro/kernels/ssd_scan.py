@@ -11,6 +11,17 @@ the running state h (N x P fp32) lives in VMEM scratch; each chunk does
 with Q = chunk length (e.g. 256), so VMEM holds Q x max(N, P, Q) fp32
 tiles (~1 MiB) and the HBM traffic is one pass over x/B/C per layer — the
 property that makes SSD linear in sequence length on TPU.
+
+The kernel reads its operands head-major (``(B, H, S, P)``, ``(B, G, S, N)``)
+so that every block is a ``(Q, P)`` or ``(Q, N)`` tile: the TPU compiler
+requires the last two block dims to be tiled (multiples of 8 and 128) or
+whole.  The within-chunk log-decay prefix L is computed by the wrapper and
+passed twice, as a ``(Q, 1)`` column and a ``(1, Q)`` row, because the
+kernel needs both orientations and Mosaic lowers neither ``cumsum`` nor a
+transpose of a vector.  The wrapper keeps the sequence-major contract of
+``models/mamba.py`` and transposes x, B, C and y on every call, which adds
+a read and a write of each to the one pass above; a model that wires this
+kernel in should keep those head-major and drop the transposes.
 """
 
 from __future__ import annotations
@@ -25,11 +36,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(
-    x_ref,  # (1, Q, 1, P)
-    a_ref,  # (1, Q, 1)
-    b_ref,  # (1, Q, 1, N)
-    c_ref,  # (1, Q, 1, N)
-    y_ref,  # (1, Q, 1, P)
+    x_ref,  # (1, 1, Q, P)
+    lc_ref,  # (1, 1, Q, 1) inclusive log-decay prefix, column
+    lr_ref,  # (1, 1, 1, Q) the same prefix, row
+    b_ref,  # (1, 1, Q, N)
+    c_ref,  # (1, 1, Q, N)
+    y_ref,  # (1, 1, Q, P)
     hout_ref,  # (1, 1, N, P) final state (written at last chunk)
     h_ref,  # scratch (N, P) fp32
     *,
@@ -42,34 +54,37 @@ def _kernel(
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    x = x_ref[0, :, 0].astype(jnp.float32)  # (Q, P)
-    a = a_ref[0, :, 0].astype(jnp.float32)  # (Q,)
-    b = b_ref[0, :, 0].astype(jnp.float32)  # (Q, N)
-    c = c_ref[0, :, 0].astype(jnp.float32)  # (Q, N)
-    L = jnp.cumsum(a)  # (Q,) inclusive log-decay prefix
+    x = x_ref[0, 0].astype(jnp.float32)  # (Q, P)
+    lc = lc_ref[0, 0]  # (Q, 1)
+    lr = lr_ref[0, 0]  # (1, Q)
+    b = b_ref[0, 0].astype(jnp.float32)  # (Q, N)
+    c = c_ref[0, 0].astype(jnp.float32)  # (Q, N)
+    # L at the chunk's end, (1, 1); a masked sum, since Mosaic cannot
+    # broadcast a slice taken at the last lane
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, Q), 1) == Q - 1
+    l_last = jnp.sum(jnp.where(last, lr, 0.0), axis=1, keepdims=True)
     # ---- intra-chunk quadratic term ----
     scores = jax.lax.dot_general(
         c, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # (Q, Q) = C_i . B_j
-    decay = L[:, None] - L[None, :]
     iq = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     jq = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    gate = jnp.exp(jnp.where(iq >= jq, decay, -jnp.inf))
+    gate = jnp.exp(jnp.where(iq >= jq, lc - lr, -jnp.inf))
     y = jax.lax.dot_general(
         scores * gate, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )  # (Q, P)
     # ---- inter-chunk: carried state ----
     y += jax.lax.dot_general(
         c, h_ref[...], (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    ) * jnp.exp(L)[:, None]
+    ) * jnp.exp(lc)
     # ---- state update ----
-    seg = jnp.exp(L[-1] - L)  # (Q,)
-    h_new = h_ref[...] * jnp.exp(L[-1]) + jax.lax.dot_general(
-        b * seg[:, None], x, (((0,), (0,)), ((), ())),
+    seg = jnp.exp(l_last - lc)  # (Q, 1)
+    h_new = h_ref[...] * jnp.exp(l_last) + jax.lax.dot_general(
+        b * seg, x, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )  # (N, P)
     h_ref[...] = h_new
-    y_ref[0, :, 0] = y.astype(y_ref.dtype)
+    y_ref[0, 0] = y.astype(y_ref.dtype)
 
     @pl.when(ci == nc - 1)
     def _final():
@@ -94,25 +109,34 @@ def ssd_scan(
     assert S % Q == 0
     nc = S // Q
 
+    # inclusive log-decay prefix within each chunk, head-major: (B, H, S)
+    L = jnp.cumsum(
+        log_dA.astype(jnp.float32).reshape(B, nc, Q, H), axis=2
+    ).reshape(B, S, H).transpose(0, 2, 1)
+    xh = jnp.swapaxes(x, 1, 2)  # (B, H, S, P)
+    bh_ = jnp.swapaxes(Bm, 1, 2)  # (B, G, S, N)
+    ch_ = jnp.swapaxes(Cm, 1, 2)
+
     kernel = functools.partial(_kernel, Q=Q, nc=nc)
     y, h = pl.pallas_call(
         kernel,
         grid=(B * H, nc),
         in_specs=[
-            pl.BlockSpec((1, Q, 1, P), lambda bh, ci: (bh // H, ci, bh % H, 0)),
-            pl.BlockSpec((1, Q, 1), lambda bh, ci: (bh // H, ci, bh % H)),
-            pl.BlockSpec((1, Q, 1, N), lambda bh, ci: (bh // H, ci, (bh % H) // rep, 0)),
-            pl.BlockSpec((1, Q, 1, N), lambda bh, ci: (bh // H, ci, (bh % H) // rep, 0)),
+            pl.BlockSpec((1, 1, Q, P), lambda bh, ci: (bh // H, bh % H, ci, 0)),
+            pl.BlockSpec((1, 1, Q, 1), lambda bh, ci: (bh // H, bh % H, ci, 0)),
+            pl.BlockSpec((1, 1, 1, Q), lambda bh, ci: (bh // H, bh % H, 0, ci)),
+            pl.BlockSpec((1, 1, Q, N), lambda bh, ci: (bh // H, (bh % H) // rep, ci, 0)),
+            pl.BlockSpec((1, 1, Q, N), lambda bh, ci: (bh // H, (bh % H) // rep, ci, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, Q, 1, P), lambda bh, ci: (bh // H, ci, bh % H, 0)),
+            pl.BlockSpec((1, 1, Q, P), lambda bh, ci: (bh // H, bh % H, ci, 0)),
             pl.BlockSpec((1, 1, N, P), lambda bh, ci: (bh // H, bh % H, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, S, H, P), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, S, P), jnp.float32),
             jax.ShapeDtypeStruct((B, H, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
         interpret=interpret,
-    )(x, log_dA, Bm, Cm)
-    return y, h
+    )(xh, L[..., None], L[:, :, None, :], bh_, ch_)
+    return jnp.swapaxes(y, 1, 2), h

@@ -1,7 +1,13 @@
 """Serving launcher: prefill a batch of prompts, then decode greedily.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch mamba2-370m --smoke \
-      --prompt-len 64 --decode-steps 16 --batch 2
+Without ``--smoke`` the registered config serves from a ``("data",
+"model")`` mesh over every device present; ``--smoke`` serves the reduced
+config from the default device (how it runs on a CPU).
+
+  PYTHONPATH=src python -m repro.launch.serve --arch mamba2-370m \
+      --prompt-len 512 --decode-steps 16 --batch 4
+  PYTHONPATH=src JAX_PLATFORMS=cpu python -m repro.launch.serve \
+      --arch mamba2-370m --smoke --prompt-len 64 --decode-steps 16 --batch 2
 """
 
 from __future__ import annotations
@@ -14,7 +20,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, smoke_config
-from repro.launch.mesh import batch_axes_of, make_production_mesh
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_host_mesh
 from repro.train.steps import make_serve_bundle
 
 
@@ -28,19 +35,18 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch)
-    mesh = None
     if args.smoke:
-        cfg = smoke_config(cfg)
+        cfg, mesh = smoke_config(cfg), None
     else:
-        mesh = make_production_mesh()
-    batch_axes = batch_axes_of(mesh) if mesh is not None else ("data",)
+        mesh = make_host_mesh()
     max_len = args.prompt_len + args.decode_steps
-    bundle = make_serve_bundle(
-        cfg, mesh, batch_axes, batch=args.batch, max_len=max_len
-    )
+    bundle = make_serve_bundle(cfg, mesh, batch=args.batch, max_len=max_len)
     key = jax.random.PRNGKey(args.seed)
     params = bundle.model.init(key)
+    if bundle.param_shardings is not None:
+        params = jax.device_put(params, bundle.param_shardings)
     tokens = jax.random.randint(
         key, (args.batch, args.prompt_len), 0, cfg.vocab_size, jnp.int32
     )

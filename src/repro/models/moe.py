@@ -25,11 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-if hasattr(jax, "shard_map"):  # jax >= 0.6
-    _shard_map = jax.shard_map
-else:  # jax 0.4.x: pre-promotion location
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.configs.base import ArchConfig, MoEConfig
 from repro.models.common import swiglu, swiglu_def
 from repro.models.params import ParamDef, fan_in_init, normal_init
@@ -182,7 +177,7 @@ def moe_forward(
         buf, dest = _local_dispatch(xt_l, idx_l, C, E)
         return buf[None], dest[None]  # add shard dim
 
-    buf, dest = _shard_map(
+    buf, dest = jax.shard_map(
         dispatch,
         mesh=mesh,
         in_specs=(P(bspec, None), P(bspec, None)),
@@ -221,7 +216,7 @@ def moe_forward(
         rows = y_pad[dest_l].reshape(-1, cfg.moe.top_k, D)  # (T, k, D)
         return jnp.einsum("tkd,tk->td", rows, w_l.astype(y_l.dtype))
 
-    out = _shard_map(
+    out = jax.shard_map(
         combine,
         mesh=mesh,
         in_specs=(P(bspec, None, None), P(bspec, None), P(bspec, None)),

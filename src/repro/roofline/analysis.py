@@ -155,7 +155,7 @@ def analyze(
     flops = float(cost.get("flops", 0.0))
     nbytes = float(cost.get("bytes accessed", 0.0))
     colls = parse_collectives(hlo_text)
-    compute_s = flops / hw.PEAK_FLOPS_BF16
+    compute_s = flops / hw.MODELLED_PEAK_FLOPS_BF16
     memory_s = nbytes / hw.HBM_BW
     collective_s = colls.total_operand_bytes / hw.ICI_BW
     terms = {
@@ -280,7 +280,7 @@ def analytic_roofline(cfg, shape, num_chips: int, microbatches: int = 8) -> Roof
     flops = mf_global / num_chips
     nbytes = analytic_hbm_bytes(cfg, shape, num_chips, microbatches=microbatches)
     coll = analytic_collective_bytes(cfg, shape, num_chips)
-    compute_s = flops / hw.PEAK_FLOPS_BF16
+    compute_s = flops / hw.MODELLED_PEAK_FLOPS_BF16
     memory_s = nbytes / hw.HBM_BW
     collective_s = coll / hw.ICI_BW
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}

@@ -1,10 +1,28 @@
-"""Target hardware constants (TPU v5e, per assignment).
+"""Hardware constants.
 
-The container is CPU-only; these constants parametrize the analytical
-roofline derived from the compiled dry-run artifacts.
+``PEAK_FLOPS_BF16_BY_KIND`` holds published per-chip peaks keyed by
+``jax.Device.device_kind``; code that times real steps on a device takes its
+peak from there (``peak_flops_bf16``).  The ``MODELLED_*`` and remaining
+constants describe a TPU v5e for the analytic models (dry-run roofline,
+calibration bridge, cluster power model), which run without a chip.
 """
 
-PEAK_FLOPS_BF16 = 197e12  # FLOP/s per chip
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip
+PEAK_FLOPS_BF16_BY_KIND = {"TPU v5 lite": 197e12}
+
+
+def peak_flops_bf16(device_kind: str) -> float:
+    """Published bf16 peak FLOP/s of one chip of ``device_kind``."""
+    try:
+        return PEAK_FLOPS_BF16_BY_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peak for device kind {device_kind!r}; "
+            f"known: {sorted(PEAK_FLOPS_BF16_BY_KIND)}"
+        ) from None
+
+
+MODELLED_PEAK_FLOPS_BF16 = PEAK_FLOPS_BF16_BY_KIND["TPU v5 lite"]
 HBM_BW = 819e9  # bytes/s per chip
 ICI_BW = 50e9  # bytes/s per link
 HBM_BYTES = 16 * 2**30  # v5e HBM capacity per chip

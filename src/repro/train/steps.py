@@ -49,12 +49,11 @@ class TrainBundle:
     batch_shardings: Dict[str, Any]
 
     def init_state(self, seed: int = 0):
-        params = self.model.init(jax.random.PRNGKey(seed))
-        if self.param_shardings is not None:
-            params = jax.tree.map(
-                lambda a, s: jax.device_put(a, s), params, self.param_shardings
-            )
-        opt_state = self.optimizer.init(params)
+        # create each shard where it lives: built whole, the fp32 optimizer
+        # state of a model that needs a mesh would not fit on one device
+        key = jax.random.PRNGKey(seed)
+        params = jax.jit(self.model.init, out_shardings=self.param_shardings)(key)
+        opt_state = jax.jit(self.optimizer.init, out_shardings=self.opt_shardings)(params)
         return params, opt_state
 
 

@@ -11,7 +11,8 @@ Wraps a :class:`TrainBundle` with:
     scheduler; exclusion from the DP group at the next epoch in a real
     multi-host run),
   * loss-spike detection with rollback (restore last snapshot, skip the
-    offending data window).
+    offending data window); a non-finite loss with no snapshot to return
+    to raises ``FloatingPointError`` instead of training on.
 """
 
 from __future__ import annotations
@@ -117,11 +118,11 @@ class Trainer:
         while self.step < c.total_steps:
             batch = self._batch(self.step)
             t0 = time.perf_counter()
-            self.params, self.opt_state, metrics = self.bundle.step_fn(
-                self.params, self.opt_state, batch
+            self.params, self.opt_state, metrics = jax.block_until_ready(
+                self.bundle.step_fn(self.params, self.opt_state, batch)
             )
-            loss = float(metrics["loss"])
             dt = time.perf_counter() - t0
+            loss = float(metrics["loss"])
             self.step += 1
             self._track(dt, loss)
             self.history.append({"step": self.step, "loss": loss, "step_s": dt})
@@ -135,7 +136,7 @@ class Trainer:
             if not math.isfinite(loss) or (
                 self._ewma_loss and loss > c.loss_spike_factor * self._ewma_loss
             ):
-                self._rollback()
+                self._rollback(loss)
                 continue
             if self.ckpt and (
                 self.step % c.ckpt_every_steps == 0
@@ -175,13 +176,20 @@ class Trainer:
                 loss if self._ewma_loss is None else (1 - a) * self._ewma_loss + a * loss
             )
 
-    def _rollback(self) -> None:
-        """Loss spike / NaN: restore the last snapshot and skip ahead."""
+    def _rollback(self, loss: float) -> None:
+        """Loss spike / NaN: restore the last snapshot and skip ahead.
+
+        Every detected spike counts in ``rollbacks``, also a finite one
+        with no snapshot to return to, which trains on."""
         self.rollbacks += 1
-        if not self.cfg.ckpt_dir:
-            return
-        path = latest_checkpoint(self.cfg.ckpt_dir)
+        print(f"step {self.step}: loss spike {loss}, rollback", flush=True)
+        path = latest_checkpoint(self.cfg.ckpt_dir) if self.cfg.ckpt_dir else None
         if path is None:
+            if not math.isfinite(loss):
+                raise FloatingPointError(
+                    f"non-finite loss {loss} at step {self.step} and no "
+                    "checkpoint to roll back to"
+                )
             return
         if self.ckpt:
             self.ckpt.wait()

@@ -47,7 +47,7 @@ def test_banded_attention_matches_masked(rng):
 def test_moe_sort_matches_onehot(rng):
     """The production sort-dispatch equals the dense one-hot oracle (same
     capacity semantics) on a single shard."""
-    from repro.launch.mesh import make_smoke_mesh
+    from repro.launch.mesh import make_host_mesh
     from repro.models import moe as moe_mod
     from repro.models.params import init_params
 
@@ -55,7 +55,7 @@ def test_moe_sort_matches_onehot(rng):
     defs = moe_mod.moe_def(cfg)
     params = init_params(defs, jax.random.PRNGKey(0))
     x = _arr(rng, 2, 16, cfg.d_model)
-    mesh = make_smoke_mesh()
+    mesh = make_host_mesh(jax.devices()[:1])
     out_sort, aux_sort = jax.jit(
         lambda p, x: moe_mod.moe_forward(p, cfg, x, mesh, ("data",))
     )(params, x)

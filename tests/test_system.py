@@ -21,6 +21,7 @@ from repro.colocation.profiler import EarlyStageProfiler
 from repro.colocation.stepper import ColocatedJob, TemporalStepper
 from repro.configs import get_config, smoke_config
 from repro.data.pipeline import DataConfig, SyntheticPipeline
+from repro.roofline import hw
 from repro.train.steps import make_train_bundle
 from repro.train.trainer import Trainer, TrainerConfig
 
@@ -116,7 +117,11 @@ def test_stepper_evict_restores_epoch_checkpoint():
 
 def test_early_stage_profiler_reports_inflation():
     jobs = [_job("minitron-8b", 0), _job("internvl2-2b", 1)]
-    prof = EarlyStageProfiler(flops_per_step={j.name: 1e9 for j in jobs})
+    # a CPU has no published peak: divide by the modelled v5e one
+    prof = EarlyStageProfiler(
+        flops_per_step={j.name: 1e9 for j in jobs},
+        peak_flops=hw.MODELLED_PEAK_FLOPS_BF16,
+    )
     stepper = TemporalStepper(jobs)
     solo = prof.profile_solo(stepper, steps=2)
     obs = prof.observe(stepper, rounds=2)
@@ -128,9 +133,9 @@ def test_early_stage_profiler_reports_inflation():
 
 def test_spatial_mesh_split():
     from repro.colocation.spatial import split_mesh, submesh_for_job
-    from repro.launch.mesh import make_smoke_mesh
+    from repro.launch.mesh import make_host_mesh
 
-    mesh = make_smoke_mesh()  # (1, 1)
+    mesh = make_host_mesh(jax.devices()[:1])  # (1, 1)
     subs = split_mesh(mesh, 1, axis="data")
     assert len(subs) == 1 and subs[0].axis_names == mesh.axis_names
     sub = submesh_for_job(mesh, 0, 1, axis="data")
