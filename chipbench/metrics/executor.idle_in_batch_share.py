@@ -1,0 +1,9 @@
+"""Share of the traced window in which the device idled while the host was
+inside ``repro.stepper.batch`` (the stepper preparing a job's batch), on the
+device's clock, in percent (averaged over the cell's chips; chipbench/spans.py)."""
+
+from chipbench import spans
+
+
+def read(record):
+    return spans.idle_share(record, "batch")

@@ -24,6 +24,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint.checkpoint import AsyncCheckpointer, latest_checkpoint, restore_checkpoint
 from repro.data.pipeline import DataConfig, SyntheticPipeline
@@ -140,12 +141,15 @@ class TemporalStepper:
                 dt = job.bundle.step_seconds(live)
                 loss = job.bundle.loss_at(job.step)
             else:
-                batch = self._make_batch(job)
-                t0 = time.perf_counter()
-                job.params, job.opt_state, m = jax.block_until_ready(
-                    job.bundle.step_fn(job.params, job.opt_state, batch)
-                )
-                dt = time.perf_counter() - t0
+                # profiler spans; the metadata is formatted only while tracing
+                with TraceAnnotation("repro.stepper.batch", job=job.name, step=job.step):
+                    batch = self._make_batch(job)
+                with TraceAnnotation("repro.stepper.step", job=job.name, step=job.step):
+                    t0 = time.perf_counter()
+                    job.params, job.opt_state, m = jax.block_until_ready(
+                        job.bundle.step_fn(job.params, job.opt_state, batch)
+                    )
+                    dt = time.perf_counter() - t0
                 loss = float(m["loss"])
                 if not math.isfinite(loss):
                     raise FloatingPointError(

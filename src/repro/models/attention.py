@@ -28,6 +28,7 @@ from repro.models.common import (
     banded_attention,
     head_rmsnorm,
     rmsnorm,
+    scope,
 )
 from repro.models.params import ParamDef, fan_in_init, ones_init
 
@@ -75,6 +76,7 @@ def _gqa_qkv(
     return q, k, v
 
 
+@scope("attention")
 def gqa_forward(
     p: Dict[str, jax.Array],
     cfg: ArchConfig,
@@ -289,6 +291,7 @@ def _mla_ckv(p, cfg, x, positions):
     return ckv, k_rope
 
 
+@scope("attention")
 def mla_forward(
     p: Dict[str, jax.Array],
     cfg: ArchConfig,

@@ -9,14 +9,40 @@ of the Pallas flash kernel in ``repro.kernels`` (which is the TPU hot path).
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.models import flags
 from repro.models.params import ParamDef, fan_in_init, normal_init, ones_init
+
+# ---------------------------------------------------------------------------
+# Layer-kind scopes
+# ---------------------------------------------------------------------------
+
+
+def scope(name: str) -> Callable[[Callable], Callable]:
+    """Run the decorated function under ``jax.named_scope(name)``.
+
+    The scope names the layer kind in every operation's ``op_name``
+    metadata, through scans, remat and the backward pass, so a device
+    trace can attribute time to it; the compiled program is otherwise the
+    same.  ``jax.named_scope`` is looked up at each call.
+    """
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+
+        return scoped
+
+    return wrap
+
 
 # ---------------------------------------------------------------------------
 # Norms
@@ -83,6 +109,7 @@ def lm_head_def(d_model: int, vocab: int) -> Dict[str, ParamDef]:
     return {"w": ParamDef((d_model, vocab), (None, "model"), fan_in_init())}
 
 
+@scope("head")
 def chunked_cross_entropy(
     head_w: jax.Array,
     hidden: jax.Array,
@@ -140,6 +167,7 @@ def swiglu_def(d_model: int, d_ff: int) -> Dict[str, ParamDef]:
     }
 
 
+@scope("mlp")
 def swiglu(params: Dict[str, jax.Array], x: jax.Array) -> jax.Array:
     g = jnp.einsum("...d,df->...f", x, params["gate"])
     u = jnp.einsum("...d,df->...f", x, params["up"])
@@ -161,6 +189,7 @@ def _repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
     )
 
 
+@scope("attention_core")
 def attention(
     q: jax.Array,  # (B, Sq, H, D)
     k: jax.Array,  # (B, Sk, Hkv, D)
@@ -222,6 +251,7 @@ def attention(
     return out
 
 
+@scope("attention_core")
 def banded_attention(
     q: jax.Array,
     k: jax.Array,

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, SSMConfig
 from repro.models import flags
-from repro.models.common import rmsnorm
+from repro.models.common import rmsnorm, scope
 from repro.models.params import (
     ParamDef,
     const_init,
@@ -89,6 +89,7 @@ def _proj_inputs(p, cfg, x):
     return z, xs, bc, dt
 
 
+@scope("ssd_scan")
 def ssd_chunked(
     x: jax.Array,  # (B, S, H, P) already dt-scaled *inputs* (dt*x)
     log_dA: jax.Array,  # (B, S, H) fp32, negative
@@ -154,6 +155,7 @@ def ssd_chunked(
     return y, h_final
 
 
+@scope("ssm")
 def mamba_forward(
     p: Dict[str, jax.Array], cfg: ArchConfig, x: jax.Array
 ) -> jax.Array:

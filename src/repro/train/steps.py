@@ -164,9 +164,10 @@ def make_train_bundle(
             (loss, metrics), grads = jax.value_and_grad(loss_of, has_aux=True)(
                 params, batch
             )
-        grads, gnorm = clip_by_global_norm(grads, grad_clip)
-        lr = lr_schedule(opt_state.step)
-        params, opt_state = optimizer.update(grads, opt_state, params, lr)
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, grad_clip)
+            lr = lr_schedule(opt_state.step)
+            params, opt_state = optimizer.update(grads, opt_state, params, lr)
         out_metrics = {
             "loss": loss.astype(jnp.float32),
             "grad_norm": gnorm,
