@@ -31,10 +31,9 @@ def full_unroll(enabled: bool = True):
         FULL_UNROLL = prev
 
 
-def scan(body, init, xs, length: int | None = None, unroll: int | None = None):
-    """lax.scan honoring FULL_UNROLL (dry-run cost-accounting mode)."""
+def scan(body, init, xs, length: int | None = None, unroll: int = 1):
+    """lax.scan honoring FULL_UNROLL (dry-run cost-accounting mode);
+    ``unroll`` is the iterations per trip of the rolled form."""
     if length is None:
         length = jax.tree.leaves(xs)[0].shape[0]
-    if unroll is None:
-        unroll = length if FULL_UNROLL else 1
-    return jax.lax.scan(body, init, xs, length=length, unroll=unroll)
+    return jax.lax.scan(body, init, xs, length=length, unroll=length if FULL_UNROLL else unroll)

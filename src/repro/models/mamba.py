@@ -1,10 +1,22 @@
 """Mamba-2 (state-space duality) mixer.
 
-Implements the chunked SSD algorithm (Dao & Gu, 2024) in pure JAX:
-within-chunk quadratic ("attention-like") term + across-chunk linear
-recurrence carried by one ``lax.scan``.  The per-chunk working set is
-O(Q^2 * H) so long sequences stream — the same blocking the Pallas
-``ssd_scan`` kernel uses on TPU (``repro.kernels.ssd_scan``).
+Implements the chunked SSD algorithm (Dao & Gu, 2024, section 6) in pure
+JAX, chunk-parallel: the sequence is cut into chunks of Q steps and every
+chunk is a batch entry of the same batched products.
+
+* within a chunk, the quadratic ("attention-like") term: C.B^T formed once
+  per group, gated per head by exp(L_i - L_j) of the within-chunk log-decay
+  prefix L (masked to -inf above the diagonal before the exp), then
+  multiplied into x;
+* each chunk's own final state, sum_j exp(L_end - L_j) B_j x_j^T, for all
+  chunks in one product;
+* across chunks, the linear recurrence h <- exp(L_end) h + state carried
+  over the chunk states alone, by one short ``lax.scan`` (unrolled);
+* the carried-in state read out by C and decayed by exp(L).
+
+Every chunk is in flight at once, so the working set is O(S * Q * H): the
+gated C.B^T of all chunks.  The Pallas ``ssd_scan`` kernel
+(``repro.kernels.ssd_scan``, forward only) streams the chunks instead.
 
 Decode is the O(1) recurrent update: ``h = dA*h + dt*x (x) B; y = C.h + D*x``
 — this is why the ``long_500k`` cell runs for SSM/hybrid archs.
@@ -30,6 +42,11 @@ from repro.models.params import (
 )
 
 Cache = Dict[str, jax.Array]
+
+# chunks of the across-chunk state carry per loop trip: a rolled loop's
+# per-trip cost outweighs its few elementwise ops, so short sequences
+# (up to 8 chunks) run it as straight-line code
+CARRY_UNROLL = 8
 
 
 def _dims(cfg: ArchConfig) -> Tuple[int, int, int, int, int]:
@@ -101,7 +118,7 @@ def ssd_chunked(
     """Chunked SSD scan. Returns (y (B,S,H,P), final state (B,H,N,P))."""
     B, S, H, P_ = x.shape
     G, N = Bm.shape[2], Bm.shape[3]
-    rep = H // G
+    R = H // G  # heads per group
     Q = min(chunk, S)
     S_orig = S
     if S % Q:
@@ -114,45 +131,54 @@ def ssd_chunked(
         Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0), (0, 0)))
         S = S + pad
     nc = S // Q
+    K = nc * G
+    f32 = jnp.float32
 
     def to_chunks(a):
-        return a.reshape((B, nc, Q) + a.shape[2:]).swapaxes(0, 1)
+        # (B, S, G, ...) -> (B, nc*G, Q, ...): every (chunk, group) is a
+        # batch entry; a free reshape when G == 1
+        a = a.reshape((B, nc, Q, G) + a.shape[3:])
+        return jnp.moveaxis(a, 3, 2).reshape((B, K, Q) + a.shape[4:])
 
-    xc, ac = to_chunks(x), to_chunks(log_dA)
-    Bc, Cc = to_chunks(Bm), to_chunks(Cm)
+    xk = to_chunks(x.reshape(B, S, G, R, P_)).astype(f32)  # (B,K,Q,R,P)
+    Bk = to_chunks(Bm).astype(f32)  # (B,K,Q,N)
+    Ck = to_chunks(Cm).astype(f32)
+    L = jnp.cumsum(to_chunks(log_dA.reshape(B, S, G, R)), axis=2)  # (B,K,Q,R) inclusive
+    L_end = L[:, :, -1]  # (B,K,R): log-decay over the whole chunk
     if h_init is None:
-        h_init = jnp.zeros((B, H, N, P_), jnp.float32)
+        h_init = jnp.zeros((B, H, N, P_), f32)
 
-    def body(h, xs):
-        xq, aq, bq, cq = xs  # (B,Q,H,P), (B,Q,H), (B,Q,G,N), (B,Q,G,N)
-        L = jnp.cumsum(aq, axis=1)  # (B,Q,H) inclusive
-        # broadcast groups to heads
-        bqh = jnp.repeat(bq, rep, axis=2) if rep > 1 else bq  # (B,Q,H,N)
-        cqh = jnp.repeat(cq, rep, axis=2) if rep > 1 else cq
-        # ---- intra-chunk (quadratic in Q) ----
-        scores = jnp.einsum("bihn,bjhn->bhij", cqh.astype(jnp.float32), bqh.astype(jnp.float32))
-        decay = L[:, :, None, :] - L[:, None, :, :]  # (B,i,j,H) = L_i - L_j
-        decay = jnp.transpose(decay, (0, 3, 1, 2))  # (B,H,i,j)
-        iq = jnp.arange(Q)
-        mask = iq[:, None] >= iq[None, :]
-        # mask BEFORE exp: exp of the (positive) upper triangle would overflow
-        # and poison gradients through the 0*inf product.
-        gate = jnp.exp(jnp.where(mask, decay, -jnp.inf))
-        y_intra = jnp.einsum("bhij,bjhp->bihp", scores * gate, xq.astype(jnp.float32))
-        # ---- inter-chunk: contribution of carried state ----
-        y_inter = jnp.einsum("bihn,bhnp->bihp", cqh.astype(jnp.float32), h)
-        y_inter = y_inter * jnp.exp(L).transpose(0, 1, 2)[..., None]  # (B,Q,H,1)
-        # ---- state update ----
-        seg = jnp.exp(L[:, -1:, :] - L)  # decay from step j to chunk end
-        h_chunk = jnp.einsum(
-            "bjhn,bjhp->bhnp", bqh.astype(jnp.float32) * seg[..., None], xq.astype(jnp.float32)
-        )
-        h_next = h * jnp.exp(L[:, -1, :])[:, :, None, None] + h_chunk
-        return h_next, y_intra + y_inter
+    # ---- intra-chunk (quadratic in Q): C.B^T once per group ----
+    CB = jnp.einsum("bkin,bkjn->bkij", Ck, Bk)  # (B,K,Q,Q)
+    Lt = jnp.swapaxes(L, 2, 3)  # (B,K,R,Q)
+    iq = jnp.arange(Q)
+    mask = iq[:, None] >= iq[None, :]
+    # mask BEFORE exp: exp of the (positive) upper triangle would overflow
+    # and poison gradients through the 0*inf product.
+    gate = jnp.exp(jnp.where(mask, Lt[..., :, None] - Lt[..., None, :], -jnp.inf))
+    y_intra = jnp.einsum("bkrij,bkjrp->bkirp", CB[:, :, None] * gate, xk)
 
-    h_final, yc = flags.scan(body, h_init, (xc, ac, Bc, Cc))
-    y = yc.swapaxes(0, 1).reshape(B, S, H, P_)[:, :S_orig]
-    return y, h_final
+    # ---- each chunk's own state: decay from step j to the chunk's end ----
+    seg = jnp.exp(L_end[:, :, None] - L)  # (B,K,Q,R)
+    states = jnp.einsum("bkjn,bkjrp->bkrnp", Bk, xk * seg[..., None])
+
+    # ---- across chunks: carry only the (B,H,N,P) states ----
+    def carry(h, inp):
+        st, dec = inp  # (B,G,R,N,P), (B,G,R)
+        return h * jnp.exp(dec)[..., None, None] + st, h
+
+    by_chunk = lambda a: jnp.moveaxis(a.reshape((B, nc, G) + a.shape[2:]), 1, 0)
+    h0 = h_init.reshape(B, G, R, N, P_)
+    h_final, h_prev = flags.scan(
+        carry, h0, (by_chunk(states), by_chunk(L_end)), unroll=min(nc, CARRY_UNROLL)
+    )
+    h_prev = jnp.moveaxis(h_prev, 0, 1).reshape(B, K, R, N, P_)
+
+    # ---- inter-chunk: contribution of the state entering each chunk ----
+    y_inter = jnp.einsum("bkin,bkrnp->bkirp", Ck, h_prev) * jnp.exp(L)[..., None]
+    y = (y_intra + y_inter).reshape(B, nc, G, Q, R, P_)
+    y = jnp.moveaxis(y, 2, 3).reshape(B, S, H, P_)[:, :S_orig]
+    return y, h_final.reshape(B, H, N, P_)
 
 
 @scope("ssm")

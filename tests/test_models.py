@@ -1,6 +1,8 @@
 """Model-level consistency: chunked-vs-naive attention, MoE dispatch
 equivalence, SSD chunked-vs-sequential, prefill/decode agreement."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -71,16 +73,66 @@ def test_moe_sort_matches_onehot(rng):
     np.testing.assert_allclose(float(aux_sort), float(aux_oh), rtol=1e-5)
 
 
-def test_ssd_chunked_matches_sequential(rng):
-    B, S, H, P, G, N = 2, 96, 2, 8, 1, 4
+def _ssd_from(x, log_dA, Bm, Cm, h):
+    """Step-by-step SSD recurrence from the state ``h``."""
+    rep = x.shape[2] // Bm.shape[2]
+    bh, ch = jnp.repeat(Bm, rep, axis=2), jnp.repeat(Cm, rep, axis=2)
+
+    def step(h, t):
+        xt, at, bt, ct = t
+        h = h * jnp.exp(at)[..., None, None] + jnp.einsum("bhn,bhp->bhnp", bt, xt)
+        return h, jnp.einsum("bhn,bhnp->bhp", ct, h)
+
+    h, ys = jax.lax.scan(step, h, [a.swapaxes(0, 1) for a in (x, log_dA, bh, ch)])
+    return ys.swapaxes(0, 1), h
+
+
+@pytest.mark.parametrize(
+    "S,H,G,with_h_init",
+    [
+        (96, 2, 1, False),  # S a chunk multiple
+        (96, 4, 1, False),
+        (96, 4, 2, False),
+        (100, 4, 1, False),  # padded to a chunk multiple
+        (100, 4, 2, False),
+        (96, 4, 2, True),  # carried in from an earlier state
+        (100, 4, 1, True),
+        (330, 4, 2, True),  # 11 chunks: the state carry stays a loop
+    ],
+)
+def test_ssd_chunked_matches_sequential(S, H, G, with_h_init, rng):
+    """Values and the gradients of x, log_dA, B and C agree with the
+    sequential recurrence, for one and several groups, a padded last chunk
+    and a carried-in state."""
+    B, P, N = 2, 8, 4
     x = _arr(rng, B, S, H, P, dtype=jnp.float32)
     log_dA = -jnp.abs(_arr(rng, B, S, H, dtype=jnp.float32)) * 0.2
     Bm = _arr(rng, B, S, G, N, dtype=jnp.float32)
     Cm = _arr(rng, B, S, G, N, dtype=jnp.float32)
-    y, h = ssd_chunked(x, log_dA, Bm, Cm, chunk=32)
-    ye, he = ref.ssd_ref(x, log_dA, Bm, Cm)
+    dy = _arr(rng, B, S, H, P, dtype=jnp.float32)
+    dh = _arr(rng, B, H, N, P, dtype=jnp.float32)
+    if with_h_init:
+        h0 = _arr(rng, B, H, N, P, dtype=jnp.float32)
+        chunked = lambda *a: ssd_chunked(*a, chunk=32, h_init=h0)
+        sequential = lambda *a: _ssd_from(*a, h0)
+    else:
+        chunked = lambda *a: ssd_chunked(*a, chunk=32)
+        sequential = ref.ssd_ref
+
+    @functools.partial(jax.jit, static_argnums=0)
+    def values_and_grads(fn, *a):
+        out, vjp = jax.vjp(fn, *a)
+        return out, vjp((dy, dh))
+
+    args = (x, log_dA, Bm, Cm)
+    (y, h), grads = values_and_grads(chunked, *args)
+    (ye, he), grads_e = values_and_grads(sequential, *args)
     np.testing.assert_allclose(np.asarray(y), np.asarray(ye), atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(h), np.asarray(he), atol=1e-4, rtol=1e-4)
+    for name, g, ge in zip(("x", "log_dA", "B", "C"), grads, grads_e):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(ge), atol=1e-3, rtol=1e-3, err_msg=f"d/d{name}"
+        )
 
 
 @pytest.mark.parametrize(

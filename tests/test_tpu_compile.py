@@ -88,3 +88,30 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), "the Pallas kernel must be in the program"
+
+
+def test_ssd_chunked_train_cost_for_v5e(one_chip, no_persistent_cache):
+    """The model's chunked SSD scan, forward and backward under
+    ``jax.checkpoint`` as the layer loop runs it, at the mamba2-370m
+    training shapes (4 x 2048, 32 heads x 64, one group, state 128, chunk
+    256).  C.B^T is formed once per group and every chunk in one batched
+    product; repeating it over the heads inside a loop over chunks moves
+    6.6e9 bytes and holds 0.5 GB of temporaries."""
+    from repro.models import flags
+    from repro.models.mamba import ssd_chunked
+
+    B, S, H, P, G, N = 4, 2048, 32, 64, 1, 128
+
+    def fwd_bwd(x, log_dA, b, c, dy, dh):
+        scan = jax.checkpoint(lambda *a: ssd_chunked(*a, chunk=256))
+        _, vjp = jax.vjp(scan, x, log_dA, b, c)
+        return vjp((dy, dh))
+
+    shapes = [(B, S, H, P), (B, S, H), (B, S, G, N), (B, S, G, N), (B, S, H, P), (B, H, N, P)]
+    args = [jax.ShapeDtypeStruct(s, F32, sharding=one_chip) for s in shapes]
+    with flags.full_unroll():  # a loop's body would be counted once
+        compiled = jax.jit(fwd_bwd).lower(*args).compile()
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] < 4.0e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 400e6
