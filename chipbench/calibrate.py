@@ -9,7 +9,9 @@ is compared with the float32 reference.  For the first ``--control-seeds``
 seeds two more readings are taken against the same reference: the control
 (the reference with every matrix product rounded to float8) and the fault
 of half of each batch left out.  A step that returns its state unchanged
-reads 1 on ``update`` by construction and is not run.  Each seed writes one
+reads 1 on ``update`` by construction and is not run.  Where the cell has
+limits, the control is also put in the program's place and judged by the
+harness's own comparison (``control_correct``).  Each seed writes one
 JSON line; the last line of standard output sums them up: per job and
 number, the largest program reading and the smallest control and fault
 readings, and the limits those readings give (``limits``); with
@@ -21,6 +23,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
 import json  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -28,8 +31,6 @@ from pathlib import Path  # noqa: E402
 ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 from chipbench import tpu_devices, use_compile_cache  # noqa: E402
-
-use_compile_cache()
 
 
 def calibrate(cell, seeds, control_seeds, devices, out, log):
@@ -66,6 +67,12 @@ def calibrate(cell, seeds, control_seeds, devices, out, log):
                 got["half_batch"] = R.gaps(flt, ref)
                 note(r.spec.name, "control", got["control"])
                 note(r.spec.name, "half_batch", got["half_batch"])
+                if cell.limits:
+                    # the control in the program's place, judged as a run
+                    # is judged, under the cell's limits as they stand
+                    compared = harness.compare(cell, [dataclasses.replace(r, readings=ctl)], {r.spec.name: ref})
+                    got["control_compared"] = compared
+                    got["control_correct"] = harness.is_correct(compared)
             line[r.spec.name] = got
         line["seconds"] = time.perf_counter() - t0
         out.write(json.dumps(line) + "\n")
@@ -111,6 +118,8 @@ def main() -> None:
     ap.add_argument("--write-limits", action="store_true")
     args = ap.parse_args()
 
+    # here and not on import: the tests import ``calibrate`` on the CPU
+    use_compile_cache()
     devices = tpu_devices()
     from chipbench import harness
 

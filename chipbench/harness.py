@@ -2,9 +2,10 @@
 the timed path produced against the plain reference.
 
 Everything that belongs to a cell is found by name: the cell's entry in
-``BENCHMARK.json``, its configuration file, its traffic mix under
-``traffic/``, its limits under ``limits/`` and each per-layer metric's
-reader under ``metrics/``.
+``BENCHMARK.json``, its configuration file, the module of the file's
+architecture under ``arch/``, its traffic mix under ``traffic/``, its
+limits under ``limits/`` and each per-layer metric's reader under
+``metrics/``.
 """
 
 from __future__ import annotations
@@ -29,6 +30,7 @@ sys.path.insert(0, str(ROOT / "src"))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+from chipbench import arch as architecture  # noqa: E402
 from chipbench import flops, peaks  # noqa: E402
 from chipbench.reference import params as P  # noqa: E402
 from chipbench.reference import train as R  # noqa: E402
@@ -147,43 +149,14 @@ def metric_reader(name: str) -> Callable[[dict], Optional[float]]:
 
 def program_config(cfg: dict):
     """The program's ``ArchConfig`` for a configuration file, checked
-    against the file's sizes."""
+    against the file's sizes by the file's architecture."""
     arch = get_config(cfg["program"]["registry"])
     changes = {}
     for k, v in cfg["program"]["replace"].items():
         cur = getattr(arch, k)
         changes[k] = dataclasses.replace(cur, **v) if isinstance(v, dict) else v
     arch = dataclasses.replace(arch, **changes)
-    if cfg["architecture"] == "transformer":
-        want = {
-            "d_model": cfg["hidden_size"],
-            "num_layers": cfg["num_hidden_layers"],
-            "num_heads": cfg["num_attention_heads"],
-            "num_kv_heads": cfg["num_key_value_heads"],
-            "resolved_head_dim": cfg["head_dim"],
-            "d_ff": cfg["intermediate_size"],
-            "vocab_size": cfg["vocab_size"],
-            "sliding_window": cfg["sliding_window"],
-            "rope_theta": cfg["rope_theta"],
-            "tie_embeddings": cfg["tie_word_embeddings"],
-        }
-        have = {k: getattr(arch, k) for k in want}
-    else:
-        s = cfg["ssm_cfg"]
-        want = {
-            "d_model": cfg["d_model"],
-            "num_layers": cfg["n_layer"],
-            "vocab_size": cfg["vocab_size"],
-            "tie_embeddings": cfg["tie_embeddings"],
-            "ssm": (s["d_state"], s["headdim"], s["expand"], s["ngroups"], s["d_conv"], s["chunk_size"]),
-        }
-        have = {k: getattr(arch, k) for k in want if k != "ssm"}
-        m = arch.ssm
-        have["ssm"] = (m.d_state, m.head_dim, m.expand, m.n_groups, m.conv_width, m.chunk)
-    have["dtype"], want["dtype"] = arch.dtype, cfg["param_dtype"]
-    bad = {k: (have[k], v) for k, v in want.items() if have[k] != v}
-    if bad:
-        raise ValueError(f"{cfg['name']}: program config differs from the file (program, file): {bad}")
+    architecture.of(cfg).check(cfg, arch)
     return arch
 
 

@@ -20,9 +20,9 @@ from typing import Any, Dict, List
 import jax
 import jax.numpy as jnp
 
-from chipbench.reference import mamba2, params as P, transformer
+from chipbench import arch
+from chipbench.reference import params as P
 
-LOSS = {"transformer": transformer.loss, "mamba2": mamba2.loss}
 # a leaf whose reference gradient is below this share of the median leaf's
 # moves under Adam by round-off alone: its change is not compared
 STILL_LEAF = 1e-3
@@ -46,7 +46,7 @@ def change_norms(cfg: dict, key: jax.Array, stored) -> Dict[str, jax.Array]:
 
 def make_step(cfg: dict, precision: str):
     t = cfg["train"]
-    loss_fn = LOSS[cfg["architecture"]]
+    loss_fn = arch.of(cfg).loss
 
     def step(stored, m, v, count, tokens, labels):
         p32 = jax.tree.map(lambda a: a.astype(jnp.float32), stored)

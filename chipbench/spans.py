@@ -3,10 +3,11 @@ time under each layer-kind scope, and each idle interval of the device
 split by the program's host spans, on the device's clock.
 
 The program names two things in the profiler's trace.  Its train step
-carries ``jax.named_scope``s (``attention``, ``attention_core`` inside it,
-``mlp``, ``ssm``, ``ssd_scan`` inside it, ``head``, ``optimizer``), which
-the device's operations keep in their ``tf_op`` metadata, through scans,
-remat and the backward pass.  Its co-location stepper wraps batch
+carries ``jax.named_scope``s, one for each kind of layer and some inside
+those (each architecture's ``SCOPES``, ``chipbench/arch``; this module
+reduces by their union, ``arch.scopes()``), which the device's
+operations keep in their ``tf_op`` metadata, through scans, remat and the
+backward pass.  Its co-location stepper wraps batch
 preparation in a ``repro.stepper.batch`` host span and the step call,
 through ``block_until_ready``, in ``repro.stepper.step``; each carries the
 job's name and the step index.
@@ -19,7 +20,8 @@ module adds, without changing any of those numbers:
   after the host left it;
 - each device operation's self time (its duration less what operations
   nested inside it on the same line cover), put down to the scopes on its
-  op name;
+  op name, and that of the collective operations (the exchange between
+  chips, named by XLA after the collective) apart;
 - each idle interval of the window split exactly by its overlap with the
   program's spans, moved onto the device's clock by the offset.
 
@@ -32,6 +34,7 @@ on standard error.  A trace of a program without these names (no
 
 from __future__ import annotations
 
+import bisect
 import collections
 import dataclasses
 import functools
@@ -40,21 +43,28 @@ import json
 import re
 import sys
 from pathlib import Path
-from typing import Dict, FrozenSet, List, Optional, Sequence, Tuple
+from typing import Collection, Dict, FrozenSet, List, Optional, Sequence, Tuple
 
-from chipbench import trace
+from chipbench import arch, trace
 
 ROOT = Path(__file__).resolve().parents[1]
 TRACE_DIR = ROOT / ".chipbench_trace"  # where run.py has the profiler write
 
-SCOPES = ("attention", "attention_core", "mlp", "ssm", "ssd_scan", "head", "optimizer")
-KINDS = ("attention", "mlp", "ssm", "head", "optimizer")  # the outer five
 UNSCOPED = "unscoped"
 PROGRAM_PREFIX = "repro."
 BATCH_SPAN = "repro.stepper.batch"
 STEP_SPAN = "repro.stepper.step"
 MODULES_LINE = "XLA Modules"
 TOP = 10
+# an operation XLA names after a collective: its synchronous form, the
+# -start and -done halves of its asynchronous one, or a fusion around it
+COLLECTIVE = re.compile(r"all-gather|reduce-scatter|all-reduce|all-to-all|collective-permute")
+
+
+def is_collective(op_name: str) -> bool:
+    """Whether a device operation (``%name = ...`` or its bare name) is part
+    of an exchange between chips."""
+    return bool(COLLECTIVE.search(op_name.split(" = ")[0]))
 
 Interval = Tuple[float, float]
 
@@ -105,16 +115,17 @@ def _xplane_pb2():
     return module
 
 
-def scopes_of(op_name: str) -> Tuple[str, ...]:
+def scopes_of(op_name: str, known: Collection[str]) -> Tuple[str, ...]:
     """The scopes on an op name, outermost first, with the transforms that
     wrap a scope taken off: ``transpose(jvp(head))`` is ``head``.  A
-    ``jit(...)`` component is a function's name, never a scope."""
+    ``jit(...)`` component is a function's name, never a scope.  ``known``
+    are the scope names (``arch.scopes()``)."""
     op_name = op_name.rsplit(":", 1)[0] if ":" in op_name else op_name
     out = []
     for part in op_name.split("/"):
         while (m := re.fullmatch(r"(\w+)\((.*)\)", part)) and m.group(1) not in ("jit", "pjit"):
             part = m.group(2)
-        if part in SCOPES:
+        if part in known:
             out.append(part)
     return tuple(out)
 
@@ -137,6 +148,7 @@ def collect(path: str) -> Optional[Trace]:
     space = pb2.XSpace()
     space.ParseFromString(Path(path).read_bytes())
     spans, ops, modules = [], {}, {}
+    known = arch.scopes()
     for plane in space.planes:
         names = {k: v.name for k, v in plane.stat_metadata.items()}
         meta = plane.event_metadata
@@ -153,7 +165,7 @@ def collect(path: str) -> Optional[Trace]:
                             tf_op = next(
                                 (_stat_value(s, names) for s in md.stats if names.get(s.metadata_id) == "tf_op"), ""
                             )
-                            kinds[e.metadata_id] = (md.name, scopes_of(tf_op), tf_op)
+                            kinds[e.metadata_id] = (md.name, scopes_of(tf_op, known), tf_op)
                         name, scopes, tf_op = kinds[e.metadata_id]
                         s = line.timestamp_ns + e.offset_ps // 1000
                         out.append(Op(name, s, s + e.duration_ps // 1000, scopes, tf_op))
@@ -228,11 +240,24 @@ def self_times(ops: Sequence[Op], lo: float, hi: float) -> List[Tuple[Op, float]
     return out
 
 
+def step_runs(runs: Sequence[Interval], ops: Sequence[Op]) -> List[Interval]:
+    """The program runs on a device that hold an operation under a layer
+    scope: the train step's, without the runs of any other program the
+    device ran beside it."""
+    starts = sorted(op.start for op in ops if op.scopes)
+    out = []
+    for s, e in runs:
+        i = bisect.bisect_left(starts, s)
+        if i < len(starts) and starts[i] <= e:
+            out.append((s, e))
+    return out
+
+
 def clock_offset(steps: Sequence[Span], runs: Dict[int, List[Interval]]) -> dict:
     """Bounds on the device's clock less the host's (ns).
 
-    Each step call runs one program, so on each device the k-th program run
-    belongs to the k-th step span: it cannot start before the host entered
+    Each step call runs one program, so on each device the k-th run of a
+    step's program (``step_runs``) belongs to the k-th step span: it cannot start before the host entered
     the span, nor end after the host left it.  ``offset_ns`` is the middle
     of the tightest bounds; it is 0 where a device's runs do not pair off
     with the steps, or where the bounds conflict."""
@@ -255,7 +280,8 @@ def reduce(tr: Trace, devices: Sequence[int]) -> dict:
 
     ``by_scope`` gives each set of scopes on an op name (joined by ``/``,
     ``unscoped`` for none) its self time in seconds, and ``under`` each
-    scope the self time of every operation it holds; ``idle_s`` splits the
+    scope the self time of every operation it holds; ``collectives_s`` is
+    the self time of the collective operations; ``idle_s`` splits the
     idle time of the window, which is ``trace.reduce``'s window less its
     busy time, into ``batch``, ``step`` and ``outside`` the program's spans.
     """
@@ -266,15 +292,18 @@ def reduce(tr: Trace, devices: Sequence[int]) -> dict:
     n = len(devices)
     program = [sp for sp in tr.spans if sp.name.startswith(PROGRAM_PREFIX)]
     steps = [sp for sp in program if sp.name == STEP_SPAN]
-    clock = clock_offset(steps, {d: tr.modules.get(d, []) for d in devices})
+    clock = clock_offset(steps, {d: step_runs(tr.modules.get(d, []), tr.ops.get(d, [])) for d in devices})
     shift = clock["offset_ns"]
     moved = {
         name: [(sp.start + shift, sp.end + shift) for sp in program if sp.name == name]
         for name in (BATCH_SPAN, STEP_SPAN)
     }
 
+    known = arch.scopes()
+    kinds = {s for s, outer in known.items() if outer is None}
     by_scope: Dict[FrozenSet[str], float] = collections.Counter()
     unscoped_ops = collections.Counter()
+    collectives = collections.Counter()
     idle = collections.Counter()
     gaps = []
     for d in devices:
@@ -283,6 +312,8 @@ def reduce(tr: Trace, devices: Sequence[int]) -> dict:
             by_scope[frozenset(op.scopes)] += t / n
             if not op.scopes:
                 unscoped_ops[(op.name.split(" = ")[0], op.tf_op)] += t / n
+            if is_collective(op.name):
+                collectives[op.name.split(" = ")[0]] += t / n
         busy = trace.union([trace._clip(op.start, op.end, lo, hi) for op, _ in timed])
         free, t = [], lo
         for s, e in busy + [(hi, hi)]:
@@ -304,8 +335,10 @@ def reduce(tr: Trace, devices: Sequence[int]) -> dict:
         "has_spans": bool(steps),
         "clock": clock,
         "by_scope": {"/".join(sorted(k)) or UNSCOPED: v * 1e-9 for k, v in by_scope.items()},
-        "under": {s: sum(v for k, v in by_scope.items() if s in k) * 1e-9 for s in SCOPES},
-        "kinds_s": sum(v for k, v in by_scope.items() if k & set(KINDS)) * 1e-9,
+        "under": {s: sum(v for k, v in by_scope.items() if s in k) * 1e-9 for s in known},
+        "kinds_s": sum(v for k, v in by_scope.items() if k & kinds) * 1e-9,
+        "collectives_s": sum(collectives.values()) * 1e-9,
+        "top_collectives": [[name, v * 1e-9] for name, v in collectives.most_common(TOP)],
         "top_unscoped": [[f"{name} {tf_op}".strip(), v * 1e-9] for (name, tf_op), v in unscoped_ops.most_common(TOP)],
         "idle_s": {k: v * 1e-9 for k, v in idle.items()},
         "idle_gaps": labelled[:TOP],
@@ -354,10 +387,21 @@ def ns_per_token(record: dict, scope: str) -> Optional[float]:
     return r["under"][scope] * 1e9 / tokens(record)
 
 
+def collective_ns_per_token(record: dict) -> Optional[float]:
+    """Device self time of the collective operations in the window per
+    token trained in it; ``None`` where the trace holds none."""
+    r = from_record(record)
+    if r is None or not r["top_collectives"] or not tokens(record):
+        return None
+    return r["collectives_s"] * 1e9 / tokens(record)
+
+
 def idle_share(record: dict, part: str) -> Optional[float]:
     """Share of the window (%) in which the device idled while the host was
-    in the program's ``part`` spans; ``None`` where it has none."""
+    in the program's ``part`` spans; ``None`` where it has none, or where
+    no step pairs with a run of its program, so that the spans cannot be
+    put on the device's clock."""
     r = from_record(record)
-    if r is None or not r["has_spans"]:
+    if r is None or not r["has_spans"] or not r["clock"]["steps_matched"]:
         return None
     return 100.0 * r["idle_s"][part] / r["window_s"]

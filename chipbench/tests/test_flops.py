@@ -1,7 +1,10 @@
 """The FLOP count against hand counts at smoke widths, and against the
-matrix products XLA is given for the program's own remat-free forward."""
+matrix products XLA is given for the program's own remat-free forward:
+exactly, by named differences, for each architecture, and within bounds for
+every configuration of the benchmark."""
 
 import dataclasses
+import json
 import math
 
 import jax
@@ -9,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import flops, peaks
+from chipbench import arch, flops, peaks
 from chipbench.harness import program_config
 from chipbench.tests import tiny
 
@@ -24,7 +27,7 @@ def test_transformer_by_hand():
     per_layer = per_token * 64 + 4 * 64 * pairs  # 5,115,904
     head = 2 * 64 * 503 * 64  # 4,120,576
     assert per_token == 73_728 and pairs == 1_552 and per_layer == 5_115_904
-    assert flops.transformer_forward(cfg, SEQ) == 2 * per_layer + head == 14_352_384
+    assert arch.of(cfg).forward_flops(cfg, SEQ) == 2 * per_layer + head == 14_352_384
     assert flops.train_step_flops(cfg, 1, SEQ) == 3 * 14_352_384
 
 
@@ -36,7 +39,7 @@ def test_mamba2_by_hand():
     chunk = 2 * tri * 16 + 2 * tri * 16 + 2 * 32 * 16 * 16 * 2 + 2 * 16 * 16  # 67,072 per head
     per_layer = proj * 64 + chunk * 2 * 8  # 4,546,560
     assert proj == 54_272 and chunk == 67_072 and per_layer == 4_546_560
-    assert flops.mamba2_forward(cfg, SEQ) == 2 * per_layer + 2 * 64 * 503 * 64 == 13_213_696
+    assert arch.of(cfg).forward_flops(cfg, SEQ) == 2 * per_layer + 2 * 64 * 503 * 64 == 13_213_696
 
 
 def _dot_flops(jaxpr) -> float:
@@ -75,7 +78,7 @@ def _program_forward(name):
 
 def test_transformer_against_xla():
     cfg, dots, xla = _program_forward("h2o-danube-1.8b-l4")
-    ours = flops.transformer_forward(cfg, SEQ)
+    ours = arch.of(cfg).forward_flops(cfg, SEQ)
     # named differences: the program scores every (query, key) pair and
     # masks what the window and causality exclude; its head spans the
     # vocabulary padded to 512
@@ -89,19 +92,34 @@ def test_transformer_against_xla():
 
 def test_mamba2_against_xla():
     cfg, dots, xla = _program_forward("mamba2-370m")
-    ours = flops.mamba2_forward(cfg, SEQ)
-    q, tri, n, p, heads, chunks, layers = 32, 528, 16, 16, 8, 2, 2
-    # named differences: the program computes the whole chunk square and
-    # masks its upper triangle; it carries the state across chunks with an
-    # elementwise multiply-add (counted here as 2 N P); padded vocabulary
-    square = 2 * (q * q - tri) * (n + p) * heads * chunks * layers
+    ours = arch.of(cfg).forward_flops(cfg, SEQ)
+    q, tri, n, p, heads, groups, chunks, layers = 32, 528, 16, 16, 8, 1, 2, 2
+    # named differences: the program forms the C.B scores once per group
+    # over the whole chunk square, where the count takes them per head on
+    # the causal triangle; it multiplies the scores into the inputs over the
+    # whole square and masks its upper triangle; it carries the state across
+    # chunks with an elementwise multiply-add (counted here as 2 N P);
+    # padded vocabulary
+    scores = 2 * q * q * n * groups * chunks * layers - 2 * tri * n * heads * chunks * layers
+    square = 2 * (q * q - tri) * p * heads * chunks * layers
     carried = 2 * n * p * heads * chunks * layers
     padded = 2 * 64 * (512 - 503) * SEQ
-    assert dots == ours + square - carried + padded
+    assert dots == ours + scores + square - carried + padded
     # the rest is elementwise: the decay gates over each chunk square (exp,
     # mask, product), the convolution, SiLU, norms and the loss; at this
     # width it is more than a third of the matrix work
     assert dots < xla < 1.5 * dots
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in json.loads((tiny.ROOT / "BENCHMARK.json").read_text())["configs"]])
+def test_every_config_against_xla(name):
+    cfg, dots, xla = _program_forward(name)
+    ours = arch.of(cfg).forward_flops(cfg, SEQ)
+    # the count holds no product the program does not compute (masked and
+    # padded work aside, which it computes and the count leaves out), and
+    # XLA's count adds the elementwise work to the products
+    assert 0 < ours <= dots < xla
+    assert flops.train_step_flops(cfg, 2, SEQ) == 3 * 2 * ours
 
 
 def test_peaks_keyed_by_device_kind():

@@ -47,3 +47,21 @@ def test_every_cell_loads_and_has_limits(cell):
     for job in c.jobs:
         assert c.limits[job.name] and set(c.limits[job.name]) <= {"loss", "grad", "update"}
     assert {m["name"] for m in c.end_to_end} == {"tokens_per_s", "peak_hbm_bytes", "setup_s"}
+
+
+def test_importing_calibrate_sets_no_compile_cache():
+    # a test that imports it would otherwise fill <checkout>/.jax_cache with
+    # the CPU's programs, and a copy of the checkout that holds them fails
+    # to write the chip's own (JAX finds no access-time file for an entry)
+    import os
+    import subprocess
+    import sys
+
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    out = subprocess.run(
+        [sys.executable, "-c", "import os, chipbench.calibrate; print(os.environ.get('JAX_COMPILATION_CACHE_DIR'))"],
+        env=dict(env, PYTHONPATH=os.pathsep.join([str(ROOT), str(ROOT / "src")])),
+        cwd=ROOT, capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == "None"

@@ -1,22 +1,27 @@
 """The float32 references against the program's models (``repro.models``)
-at smoke widths, on the same weights in float32: the loss and every leaf's
-gradient agree to float32 rounding.  The program's Mamba-2 scan runs in
+at smoke widths, on the same weights in float32, for every configuration
+of the benchmark: the loss and every leaf's gradient agree to float32
+rounding.  The program's Mamba-2 scan runs in
 chunks of 32 and the reference's in chunks of 128, so the sequence of 256
 crosses chunk boundaries differently on the two sides."""
+
+import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from chipbench import arch
 from chipbench.harness import program_config
 from chipbench.reference import params as P
-from chipbench.reference import train as R
 from chipbench.tests import tiny
 from chipbench.traffic import TokenStream
 
+CONFIGS = [c["name"] for c in json.loads((tiny.ROOT / "BENCHMARK.json").read_text())["configs"]]
 
-@pytest.mark.parametrize("name", ["h2o-danube-1.8b-l4", "mamba2-370m"])
+
+@pytest.mark.parametrize("name", CONFIGS)
 def test_loss_and_gradient_match_the_program(name):
     from repro.models.factory import build_model
 
@@ -27,7 +32,7 @@ def test_loss_and_gradient_match_the_program(name):
     )
     tokens, labels = TokenStream(cfg["vocab_size"], 256, 2, seed=3).batch_at(0)
     prog = jax.value_and_grad(lambda p: model.loss(p, tokens, labels)[0])
-    ref = jax.value_and_grad(lambda p: R.LOSS[cfg["architecture"]](p, tokens, labels, cfg, "float32"))
+    ref = jax.value_and_grad(lambda p: arch.of(cfg).loss(p, tokens, labels, cfg, "float32"))
     with jax.default_matmul_precision("highest"):
         (lp, gp), (lr, gr) = jax.jit(prog)(params), jax.jit(ref)(params)
     assert float(lp) == pytest.approx(float(lr), rel=1e-5)
@@ -43,7 +48,7 @@ def test_loss_and_gradient_match_the_program(name):
 def test_window_masks_distant_keys():
     """With a window of 32 the reference's last query cannot see key 0:
     changing the first token moves only the first 32 positions' outputs."""
-    from chipbench.reference.transformer import attention
+    attention = arch.load("transformer").attention
 
     k = jax.random.normal(jax.random.key(0), (1, 64, 2, 16))
     q = jax.random.normal(jax.random.key(1), (1, 64, 4, 16))
@@ -57,7 +62,7 @@ def test_window_masks_distant_keys():
 def test_ssd_matches_the_recurrence():
     """The chunked SSD of the reference against the step-by-step recurrence
     h_t = exp(A_t) h_{t-1} + B_t x_t^T, y_t = C_t h_t."""
-    from chipbench.reference.mamba2 import ssd
+    ssd = arch.load("mamba2").ssd
 
     ks = jax.random.split(jax.random.key(0), 4)
     b, l, h, p, n = 1, 16, 2, 3, 4

@@ -8,8 +8,9 @@ from pathlib import Path
 
 import pytest
 
-from chipbench import harness, spans, trace
+from chipbench import arch, harness, spans, trace
 from chipbench.spans import Op, Span, Trace
+from chipbench.tests import tiny
 
 DATA = Path(__file__).resolve().parent / "data"
 
@@ -30,7 +31,7 @@ CORE = "jit(train_step)/transpose(jvp())/while/body/closed_call/checkpoint/atten
     ],
 )
 def test_scopes_of_an_op_name(op_name, want):
-    assert spans.scopes_of(op_name) == want
+    assert spans.scopes_of(op_name, arch.scopes()) == want
 
 
 def _op(name, s, e, *scopes):
@@ -165,6 +166,31 @@ def test_conflicting_bounds_apply_no_offset():
     )
 
 
+def test_steps_pair_only_with_runs_of_the_step_program():
+    # the device also runs a program with no scoped operation (0.5..0.6 ms
+    # after each step's run): it is left out, and the skew is found as
+    # without it
+    tr = _skewed(1 * MS)
+    other = [(e + MS // 2, e + MS * 6 // 10) for _, e in tr.modules[0]]
+    tr.modules[0] = sorted(tr.modules[0] + other)
+    tr.ops[0] += [_op("copy", s, e) for s, e in other]
+    assert spans.step_runs(tr.modules[0], tr.ops[0]) == sorted(set(tr.modules[0]) - set(other))
+    got = spans.reduce(tr, [0])["clock"]
+    assert got["steps_matched"] == 3 and got["offset_ns"] == pytest.approx(1.04 * MS)
+
+
+def test_idle_by_span_reads_nothing_where_no_step_pairs(monkeypatch):
+    # one step span and two runs of the step's program: the spans cannot be
+    # put on the device's clock, so the split by span is not read
+    tr = _skewed(1 * MS)
+    tr.spans = [sp for sp in tr.spans if sp.step != 1]
+    reduced = spans.reduce(tr, [0])
+    assert reduced["has_spans"] and reduced["clock"]["steps_matched"] == 0
+    monkeypatch.setattr(spans, "from_record", lambda r: reduced)
+    for part in ("batch", "step"):
+        assert spans.idle_share({"trace": {}}, part) is None
+
+
 def _record(tmp_path, fixture, monkeypatch, chips=1, tokens=(4096,)):
     d = tmp_path / "plugins" / "profile" / "run"
     d.mkdir(parents=True)
@@ -243,9 +269,11 @@ def test_recorded_stepper_trace():
     # the same busy and idle time as the benchmark's own reduction
     s0, o0 = trace.collect(str(DATA / "stepper_trace.xplane.pb"))
     assert trace.reduce(s0, o0, [0])["busy_s"] == pytest.approx(got["busy_s"])
-    # every scope of the two jobs is there; the tiny widths leave much of
-    # the time to the embedding and its gradient, outside every scope
-    assert all(got["under"][s] > 0 for s in spans.SCOPES)
+    # every scope of the two jobs' architectures is there; the tiny widths
+    # leave much of the time to the embedding and its gradient, outside
+    # every scope
+    jobs = {sp.job for sp in program}
+    assert all(got["under"][s] > 0 for job in jobs for s in arch.of(tiny.config(job)).SCOPES)
     assert sum(got["by_scope"].values()) == pytest.approx(got["busy_s"])
     assert got["top_unscoped"][0][0] == "%fusion.2 jit(train_step)/transpose(jvp())/scatter-add:"
 
@@ -259,3 +287,67 @@ def test_readers_on_the_recorded_stepper_trace(tmp_path, monkeypatch):
     assert read["attention.ns_per_token"] == pytest.approx(47_536 / 256)
     assert read["executor.idle_in_batch_share"] == pytest.approx(100 * 4_301_300 / 17_157_449)
     assert read["executor.idle_in_step_share"] == pytest.approx(100 * 8_336_876.5 / 17_157_449)
+
+
+@pytest.mark.parametrize(
+    "op_name,want",
+    [
+        ("%all-gather-start.3 = (bf16[2560,6912]{1,0}, bf16[5120,6912]{1,0}) all-gather-start(bf16[2560,6912]{1,0} %p.1)", True),
+        ("%all-gather-done.3 = bf16[5120,6912]{1,0} all-gather-done((bf16[2560,6912]{1,0}, bf16[5120,6912]{1,0}) %all-gather-start.3)", True),
+        ("%all-reduce.7 = f32[] all-reduce(f32[] %x), replica_groups={{0,1,2,3}}", True),
+        ("all-reduce-done.2", True),
+        ("%reduce-scatter.1 = f32[1280,6912]{1,0} reduce-scatter(f32[2560,6912]{1,0} %g)", True),
+        ("%all-to-all.4 = bf16[4,64]{1,0} all-to-all(bf16[4,64]{1,0} %t)", True),
+        ("%collective-permute-done.9 = bf16[8]{0} collective-permute-done(bf16[8]{0} %c)", True),
+        ("%all-gather-fusion.2 = bf16[5120,80]{1,0} fusion(bf16[2560,80]{1,0} %w), kind=kOutput", True),
+        ("%fusion.12 = bf16[4096,2560]{1,0} fusion(bf16[4096,6912]{1,0} %all-gather-done.3), kind=kOutput", False),
+        ("%copy-start.62 = (s32[2,64]{1,0}, s32[2,64]{1,0}, u32[]) copy-start(s32[2,64]{1,0} %t)", False),
+        ("%reduce-window.1 = f32[8,256]{1,0} reduce-window(f32[8,256]{1,0} %a, f32[] %z)", False),
+        ("%reduce.3 = f32[] reduce(f32[8]{0} %a, f32[] %z), to_apply=%add", False),
+        ("%scatter-add_fusion.1 = bf16[32000,2560]{1,0} fusion(%g), kind=kLoop", False),
+    ],
+)
+def test_collective_operations_by_name(op_name, want):
+    # by the instruction's own name: an operand that is a collective's
+    # result does not make a fusion one
+    assert spans.is_collective(op_name) is want
+
+
+def test_collective_self_time_by_hand():
+    # a layer loop 0..100 holding an all-gather started 5..8 and waited on
+    # 20..30, a product 30..60 under mlp, and a reduce-scatter fusion 60..75
+    # under mlp; after the loop an all-reduce 100..110 inside the optimizer
+    ops = [
+        _op("%while.1 = while()", 0, 100),
+        _op("%all-gather-start.1 = all-gather-start()", 5, 8, "mlp"),
+        _op("%all-gather-done.1 = all-gather-done()", 20, 30, "mlp"),
+        _op("%fusion.4 = fusion(%all-gather-done.1)", 30, 60, "mlp"),
+        _op("%reduce-scatter-fusion.1 = fusion()", 60, 75, "mlp"),
+        _op("%all-reduce.2 = all-reduce()", 100, 110, "optimizer"),
+    ]
+    tr = Trace(spans=_window(0, 120), ops={0: ops, 1: ops[:-1]}, modules={})
+    got = spans.reduce(tr, [0, 1])
+    ns = 1e-9
+    # device 0: 3 + 10 + 15 + 10 = 38; device 1: 28; averaged over the two
+    assert got["collectives_s"] == pytest.approx(33 * ns)
+    assert got["top_collectives"] == [
+        ["%reduce-scatter-fusion.1", pytest.approx(15 * ns)],
+        ["%all-gather-done.1", pytest.approx(10 * ns)],
+        ["%all-reduce.2", pytest.approx(5 * ns)],
+        ["%all-gather-start.1", pytest.approx(3 * ns)],
+    ]
+    # the collectives also count under the scopes that hold them
+    assert got["under"]["mlp"] == pytest.approx(58 * ns)
+    assert got["under"]["optimizer"] == pytest.approx(5 * ns)
+
+
+def test_collectives_reader(tmp_path, monkeypatch):
+    # recorded on one chip: no collective, so nothing to read
+    record = _record(tmp_path, "stepper_trace.xplane.pb", monkeypatch, tokens=(128, 128))
+    assert harness.metric_reader("collectives.ns_per_token")(record) is None
+    # on four devices, 38 ns of collectives on each over 4 + 4 tokens
+    ops = [_op("%all-gather-done.1 = all-gather-done()", 10, 48, "attention"), _op("%fusion.1 = fusion()", 48, 90)]
+    reduced = spans.reduce(Trace(_window(0, 100), {d: ops for d in range(4)}, {}), [0, 1, 2, 3])
+    monkeypatch.setattr(spans, "from_record", lambda r: reduced)
+    record = {"trace": {"busy_s": 1.0, "window_s": 1.0}, "chips": 4, "jobs": [{"steps": 2, "tokens_per_step": 4}]}
+    assert harness.metric_reader("collectives.ns_per_token")(record) == pytest.approx(38 / 8)

@@ -1,68 +1,45 @@
 """Cells at smoke widths, built from the real configuration and traffic
-files with every size cut down, for CPU tests."""
+files with every size cut down, for CPU tests.  A configuration's smoke
+sizes are ``tiny_sizes/<name>.json``, laid over its file."""
 
 from __future__ import annotations
 
-import copy
 import json
 from pathlib import Path
 
 from chipbench import harness
 
-BENCH = Path(__file__).resolve().parents[1]
-
-TINY = {
-    "h2o-danube-1.8b-l4": {
-        "hidden_size": 64,
-        "intermediate_size": 128,
-        "num_attention_heads": 4,
-        "num_key_value_heads": 2,
-        "head_dim": 16,
-        "num_hidden_layers": 2,
-        "vocab_size": 503,
-        "sliding_window": 32,
-        "program": {
-            "registry": "h2o-danube-1.8b",
-            "replace": {
-                "num_layers": 2, "d_model": 64, "num_heads": 4, "num_kv_heads": 2,
-                "head_dim": 16, "d_ff": 128, "vocab_size": 503, "sliding_window": 32,
-            },
-        },
-    },
-    "mamba2-370m": {
-        "d_model": 64,
-        "n_layer": 2,
-        "vocab_size": 503,
-        "ssm_cfg": {"d_state": 16, "d_conv": 4, "expand": 2, "headdim": 16, "ngroups": 1, "chunk_size": 32},
-        "program": {
-            "registry": "mamba2-370m",
-            "replace": {"num_layers": 2, "d_model": 64, "vocab_size": 503,
-                        "ssm": {"d_state": 16, "head_dim": 16, "chunk": 32}},
-        },
-    },
-}
+ROOT = Path(__file__).resolve().parents[2]
 
 
-def config(name: str) -> dict:
-    cfg = json.loads((BENCH / "configs" / f"{name}.json").read_text())
-    cfg.update(copy.deepcopy(TINY[name]))
+def config(name: str, root: Path = ROOT) -> dict:
+    """Configuration ``name`` of the benchmark under ``root``, at its smoke
+    sizes."""
+    bench = root / "chipbench"
+    cfg = json.loads((bench / "configs" / f"{name}.json").read_text())
+    cfg.update(json.loads((bench / "tests" / "tiny_sizes" / f"{name}.json").read_text()))
     return cfg
 
 
-def cell(jobs, seq=64, limits=None, mesh=None, chips=1) -> harness.Cell:
-    """A cell of ``jobs`` [(config name, batch)] at sequence ``seq``."""
+def cell(jobs=None, seq=64, limits=None, mesh=None, chips=1, traffic="solo-b4x2048", root: Path = ROOT) -> harness.Cell:
+    """A cell of ``jobs`` [(config name, batch)] (default: the mix's own
+    jobs and batches) at sequence ``seq``, under the rest of mix
+    ``traffic``; ``mesh``, where given, in place of the mix's."""
+    mix = json.loads((ROOT / "chipbench" / "traffic" / f"{traffic}.json").read_text())
+    if jobs is None:
+        jobs = [(j["config"], j["batch"]) for j in mix["jobs"]]
     specs = [
-        harness.JobSpec(name=n, cfg=config(n), batch=b, seq=seq, index=i)
+        harness.JobSpec(name=n, cfg=config(n, root), batch=b, seq=seq, index=i)
         for i, (n, b) in enumerate(jobs)
     ]
-    traffic = json.loads((BENCH / "traffic" / "solo-b4x2048.json").read_text())
-    traffic["mesh"] = mesh
-    spec = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+    if mesh is not None:
+        mix["mesh"] = mesh
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     return harness.Cell(
         name="tiny",
         chips=chips,
         jobs=specs,
-        traffic=traffic,
+        traffic=mix,
         end_to_end=spec["end_to_end"],
         per_layer=spec["per_layer"],
         limits=limits or {},
