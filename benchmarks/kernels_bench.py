@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import Row, timed_us
-from repro.kernels import ops, ref
+from repro.kernels import ops, ref, splash_attention
 
 
 def run() -> List[Row]:
@@ -25,7 +25,7 @@ def run() -> List[Row]:
     q, k, v = arr(1, 8, 512, 64), arr(1, 2, 512, 64), arr(1, 2, 512, 64)
     f = jax.jit(lambda q, k, v: ref.attention_ref(q, k, v, causal=True))
     us = timed_us(lambda: jax.block_until_ready(f(q, k, v)), iters=3)
-    out_i = ops.flash_attention(q, k, v, causal=True, backend="interpret")
+    out_i = splash_attention.flash_attention(q, k, v, interpret=True)
     err = float(
         np.abs(np.asarray(out_i, np.float32) - np.asarray(f(q, k, v), np.float32)).max()
     )

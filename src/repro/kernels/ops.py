@@ -11,30 +11,16 @@
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention as _decode_pallas
-from repro.kernels.flash_attention import flash_attention as _flash_pallas
 from repro.kernels.rmsnorm import rmsnorm as _rmsnorm_pallas
 from repro.kernels.ssd_scan import ssd_scan as _ssd_pallas
 
 DEFAULT_BACKEND = "pallas"
-
-
-@functools.partial(jax.jit, static_argnames=("causal", "window", "backend"))
-def flash_attention(
-    q, k, v, *, causal: bool = True, window: Optional[int] = None,
-    backend: str = DEFAULT_BACKEND,
-):
-    if backend == "xla":
-        return ref.attention_ref(q, k, v, causal=causal, window=window)
-    return _flash_pallas(
-        q, k, v, causal=causal, window=window, interpret=(backend == "interpret")
-    )
 
 
 @functools.partial(jax.jit, static_argnames=("backend",))

@@ -25,7 +25,7 @@ from repro.configs.base import ArchConfig
 from repro.models.common import (
     apply_rope,
     attention,
-    banded_attention,
+    causal_attention,
     head_rmsnorm,
     rmsnorm,
     scope,
@@ -83,14 +83,22 @@ def gqa_forward(
     x: jax.Array,
     positions: jax.Array,
     q_chunk: int = 1024,
+    mesh: Optional[jax.sharding.Mesh] = None,
+    batch_axes: Tuple[str, ...] = ("data",),
 ) -> jax.Array:
-    """Causal self-attention over a full sequence (train / prefill)."""
+    """Causal self-attention over a full sequence (train / prefill); the
+    path is ``causal_attention``'s choice."""
     B, S, _ = x.shape
     q, k, v = _gqa_qkv(p, cfg, x, positions)
-    if cfg.sliding_window is not None:
-        o = banded_attention(q, k, v, window=cfg.sliding_window, q_chunk=q_chunk)
-    else:
-        o = attention(q, k, v, causal=True, q_chunk=q_chunk)
+    o = causal_attention(
+        q,
+        k,
+        v,
+        window=cfg.sliding_window,
+        q_chunk=q_chunk,
+        mesh=mesh,
+        batch_axes=batch_axes,
+    )
     return jnp.einsum("bsh,hd->bsd", o.reshape(B, S, -1), p["wo"])
 
 

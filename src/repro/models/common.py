@@ -2,20 +2,25 @@
 
 Everything is written as plain functions over parameter dicts so the same
 code path serves (a) smoke tests on 1 CPU device, (b) the 512-chip dry-run
-under pjit, and (c) real training.  Attention is *chunked over queries*
-(lax.scan) so no S x S score tensor is ever materialized — the XLA analogue
-of the Pallas flash kernel in ``repro.kernels`` (which is the TPU hot path).
+under pjit, and (c) real training.  On a TPU causal self-attention runs the
+splash flash kernel (``causal_attention``); elsewhere attention is *chunked
+over queries* (lax.scan) so no S x S score tensor is ever materialized.
 """
 
 from __future__ import annotations
 
+import collections
 import functools
+import json
 import math
-from typing import Any, Callable, Dict, Optional, Tuple
+import sys
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from repro.kernels import splash_attention
 from repro.models import flags
 from repro.models.params import ParamDef, fan_in_init, normal_init, ones_init
 
@@ -309,3 +314,99 @@ def banded_attention(
         )
         out = jnp.concatenate([out, tail], axis=1)
     return out
+
+
+# ---------------------------------------------------------------------------
+# Causal self-attention: the flash kernel where it applies, else XLA
+# ---------------------------------------------------------------------------
+
+# (path, why) -> how many traces of ``causal_attention`` took it
+ATTENTION_PATHS: collections.Counter = collections.Counter()
+
+
+def attention_path(
+    platform: str,
+    q_shape: Sequence[int],  # (B, Sq, H, D)
+    k_shape: Sequence[int],  # (B, Sk, Hkv, D)
+    v_shape: Sequence[int],  # (B, Sk, Hkv, Dv)
+    shards: Tuple[int, int] = (1, 1),  # (batch shards, model shards)
+) -> Tuple[str, str]:
+    """``("kernel" | "xla", why)`` for causal self-attention of these shapes.
+
+    The kernel (``kernels/splash_attention.py``) needs a TPU, as many keys
+    as queries, a length that is a multiple of 128, one head dimension for
+    q, k and v, and a batch and heads that split evenly over the mesh.
+    """
+    B, S, H, D = q_shape
+    Sk, Hkv, Dk = k_shape[1], k_shape[2], k_shape[3]
+    if platform != "tpu":
+        return "xla", f"platform {platform}"
+    if Sk != S:
+        return "xla", f"{S} queries against {Sk} keys"
+    if S % 128:
+        return "xla", f"length {S} is not a multiple of 128"
+    if not D == Dk == v_shape[3]:
+        return "xla", f"head dims q {D}, k {Dk}, v {v_shape[3]}"
+    n_batch, n_model = shards
+    if B % n_batch or H % n_model or Hkv % n_model:
+        return "xla", f"batch {B}, heads {H}/{Hkv} over {n_batch} x {n_model} shards"
+    return "kernel", f"tpu, length {S}, head dim {D}"
+
+
+def _platform(mesh: Optional[jax.sharding.Mesh]) -> str:
+    devices = mesh.devices.flat if mesh is not None else jax.devices()
+    return next(iter(devices)).platform
+
+
+def _flash(q, k, v, window):
+    """The kernel on (B, S, H, D) arrays, head-major inside."""
+    o = splash_attention.flash_attention(
+        q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2), window=window
+    )
+    return o.swapaxes(1, 2)
+
+
+def causal_attention(
+    q: jax.Array,  # (B, S, H, D)
+    k: jax.Array,  # (B, S, Hkv, D)
+    v: jax.Array,  # (B, S, Hkv, Dv)
+    *,
+    window: Optional[int] = None,
+    q_chunk: int = 1024,
+    mesh: Optional[jax.sharding.Mesh] = None,
+    batch_axes: Tuple[str, ...] = ("data",),
+) -> jax.Array:
+    """Causal self-attention over a full sequence (train / prefill), with
+    keys older than ``window`` masked.
+
+    Takes the flash kernel where ``attention_path`` allows, under a mesh
+    through ``shard_map``: batch over ``batch_axes``, heads over ``model``
+    (query heads of one group stay with their KV head, so nothing moves
+    between chips), under the ``attention_core`` scope that the XLA path's
+    ``banded_attention`` / ``attention`` carry.  Each trace counts its path
+    in ``ATTENTION_PATHS`` and logs it on standard error.
+    """
+    shards = (1, 1)
+    if mesh is not None:
+        shards = (math.prod(mesh.shape[a] for a in batch_axes), mesh.shape["model"])
+    path, why = attention_path(_platform(mesh), q.shape, k.shape, v.shape, shards)
+    ATTENTION_PATHS[path, why] += 1
+    print(
+        "attention.path: "
+        + json.dumps({"path": path, "why": why, "q": q.shape, "k": k.shape, "window": window}),
+        file=sys.stderr,
+        flush=True,
+    )
+    if path == "xla":
+        if window is not None:
+            return banded_attention(q, k, v, window=window, q_chunk=q_chunk)
+        return attention(q, k, v, causal=True, q_chunk=q_chunk)
+    flash = functools.partial(_flash, window=window)
+    if mesh is not None:
+        bspec = batch_axes if len(batch_axes) > 1 else batch_axes[0]
+        spec = P(bspec, None, "model", None)
+        flash = jax.shard_map(
+            flash, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False
+        )
+    with jax.named_scope("attention_core"):
+        return flash(q, k, v)

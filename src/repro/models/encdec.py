@@ -145,7 +145,9 @@ class EncDecModel:
     def _dec_block(self, p, x, positions, memory):
         cfg = self.cfg
         h = rmsnorm(p["norm1"], x)
-        x = x + attn.gqa_forward(p["mixer"], cfg, h, positions, self.q_chunk)
+        x = x + attn.gqa_forward(
+            p["mixer"], cfg, h, positions, self.q_chunk, self.mesh, self.batch_axes
+        )
         h = rmsnorm(p["norm_x"], x)
         mem_kv = attn.cross_memory_kv(p["cross"], cfg, memory)
         x = x + attn.cross_forward(p["cross"], cfg, h, mem_kv, self.q_chunk)

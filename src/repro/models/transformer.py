@@ -158,7 +158,9 @@ class Model:
             if cfg.attention == "mla":
                 h = attn.mla_forward(p["mixer"], cfg, h, positions, self.q_chunk)
             else:
-                h = attn.gqa_forward(p["mixer"], cfg, h, positions, self.q_chunk)
+                h = attn.gqa_forward(
+                    p["mixer"], cfg, h, positions, self.q_chunk, self.mesh, self.batch_axes
+                )
         else:
             h = mb.mamba_forward(p["mixer"], cfg, h)
         x = x + h
@@ -458,7 +460,9 @@ class Model:
                 "kr": jax.lax.dynamic_update_slice_in_dim(c["kr"], kr, 0, axis=1),
             }
         else:
-            out = attn.gqa_forward(p["mixer"], cfg, h, positions, self.q_chunk)
+            out = attn.gqa_forward(
+                p["mixer"], cfg, h, positions, self.q_chunk, self.mesh, self.batch_axes
+            )
             _, k, v = attn._gqa_qkv(p["mixer"], cfg, h, positions)
             c = attn.gqa_make_cache(cfg, B, max_len, dtype=k.dtype)
             W = c["k"].shape[1]

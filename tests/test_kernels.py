@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import ops, ref, splash_attention
 
 
 def _arr(rng, *shape, dtype=jnp.bfloat16):
@@ -21,24 +21,23 @@ def _assert_close(a, b, dtype):
 
 
 ATTN_SHAPES = [
-    # (B, H, Hkv, Sq, Sk, D)
-    (1, 1, 1, 128, 128, 64),
-    (2, 4, 2, 256, 256, 64),
-    (1, 8, 8, 128, 128, 128),  # MHA
-    (2, 4, 1, 128, 256, 32),  # MQA, Sq != Sk
+    # (B, H, Hkv, S, D)
+    (1, 1, 1, 128, 64),
+    (2, 4, 2, 256, 64),
+    (1, 8, 8, 128, 128),  # MHA
+    (2, 4, 1, 256, 32),  # MQA
 ]
 
 
 @pytest.mark.parametrize("shape", ATTN_SHAPES)
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_flash_attention_causal(shape, dtype, rng):
-    B, H, Hkv, Sq, Sk, D = shape
-    q = _arr(rng, B, H, Sq, D, dtype=dtype)
-    k = _arr(rng, B, Hkv, Sk, D, dtype=dtype)
-    v = _arr(rng, B, Hkv, Sk, D, dtype=dtype)
-    causal = Sq == Sk  # causal only meaningful for square here
-    out = ops.flash_attention(q, k, v, causal=causal, backend="interpret")
-    exp = ref.attention_ref(q, k, v, causal=causal)
+    B, H, Hkv, S, D = shape
+    q = _arr(rng, B, H, S, D, dtype=dtype)
+    k = _arr(rng, B, Hkv, S, D, dtype=dtype)
+    v = _arr(rng, B, Hkv, S, D, dtype=dtype)
+    out = splash_attention.flash_attention(q, k, v, interpret=True)
+    exp = ref.attention_ref(q, k, v, causal=True)
     _assert_close(out, exp, dtype)
 
 
@@ -47,7 +46,7 @@ def test_flash_attention_sliding_window(window, rng):
     q = _arr(rng, 1, 4, 256, 64)
     k = _arr(rng, 1, 2, 256, 64)
     v = _arr(rng, 1, 2, 256, 64)
-    out = ops.flash_attention(q, k, v, causal=True, window=window, backend="interpret")
+    out = splash_attention.flash_attention(q, k, v, window=window, interpret=True)
     exp = ref.attention_ref(q, k, v, causal=True, window=window)
     _assert_close(out, exp, jnp.bfloat16)
 

@@ -10,13 +10,15 @@ this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import ops
+from repro.kernels import ops, splash_attention
+from repro.models import common
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
@@ -47,8 +49,11 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-def _flash(q, k, v):
-    return ops.flash_attention(q, k, v, causal=True, window=4096)
+def _flash_train(q, k, v):
+    def loss(q, k, v):
+        return jnp.sum(splash_attention.flash_attention(q, k, v, window=4096).astype(F32))
+
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
 
 def _decode(q, k, v, valid_len):
@@ -65,8 +70,8 @@ def _rmsnorm(x, scale):
 
 KERNELS = {
     # name: (fn, [(shape, dtype), ...])
-    "flash_attention": (
-        _flash,
+    "splash_attention_train": (
+        _flash_train,
         [((1, 32, 4096, 80), BF16), ((1, 8, 4096, 80), BF16), ((1, 8, 4096, 80), BF16)],
     ),
     "decode_attention": (
@@ -88,6 +93,28 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), "the Pallas kernel must be in the program"
+
+
+def test_flash_train_ops_under_attention_core(one_chip, no_persistent_cache, monkeypatch):
+    """``causal_attention`` on a TPU takes the kernel; its forward, dq and
+    dkv calls, under ``jax.checkpoint`` as the layer loop runs them, carry
+    the ``attention_core`` scope that the device trace is read by."""
+    monkeypatch.setattr(common, "_platform", lambda mesh: "tpu")
+
+    def fwd_bwd(q, k, v):
+        def loss(q, k, v):
+            return jnp.sum(common.causal_attention(q, k, v, window=4096).astype(F32))
+
+        return jax.grad(jax.checkpoint(loss), argnums=(0, 1, 2))(q, k, v)
+
+    shapes = [(1, 4096, 32, 80), (1, 4096, 8, 80), (1, 4096, 8, 80)]
+    args = [jax.ShapeDtypeStruct(s, BF16, sharding=one_chip) for s in shapes]
+    text = jax.jit(fwd_bwd).lower(*args).compile().as_text()
+    scoped = {}
+    for m in re.finditer(r"%(splash_mha_(fwd|dq|dkv)\w*)[.\d]* = ", text):
+        op_name = re.search(r'op_name="([^"]*)"', text[m.end():]).group(1)
+        scoped[m.group(2)] = "/attention_core/" in op_name
+    assert scoped == {"fwd": True, "dq": True, "dkv": True}
 
 
 def test_ssd_chunked_train_cost_for_v5e(one_chip, no_persistent_cache):
