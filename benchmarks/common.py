@@ -36,17 +36,6 @@ def save_json(name: str, payload: Any) -> str:
     return path
 
 
-def load_dryrun_records() -> List[Dict[str, Any]]:
-    d = os.path.join(ARTIFACTS, "dryrun")
-    out = []
-    if os.path.isdir(d):
-        for fn in sorted(os.listdir(d)):
-            if fn.endswith(".json"):
-                with open(os.path.join(d, fn)) as f:
-                    out.append(json.load(f))
-    return out
-
-
 def timed_us(fn: Callable, *args, iters: int = 5, warmup: int = 2) -> float:
     for _ in range(warmup):
         fn(*args)

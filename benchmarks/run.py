@@ -10,7 +10,6 @@ Prints ``name,us_per_call,derived`` CSV rows:
   serve    — mixed day: 10k-job trace + 1M-request serving, co-located vs split
   dvfs     — EaCO vs EaCO-PowerCap at 3 cluster power-cap levels (10k jobs)
   synergy  — host-aware vs host-blind EaCO on the 10k hetero trace (Synergy)
-  roofline — §Roofline terms per (arch x shape x mesh) from the dry-run
   kernels  — Pallas kernel micro-benches + interpret-mode correctness
 
 Flags:
@@ -62,8 +61,7 @@ def main() -> None:
     print("name,us_per_call,derived")
     from benchmarks import (
         dvfs_bench, elastic_bench, fig1, fig3, fig4, kernels_bench,
-        roofline_bench, scale_bench, serve_bench, synergy_bench, table1,
-        tpu_cluster,
+        scale_bench, serve_bench, synergy_bench, table1, tpu_cluster,
     )
 
     modules = [
@@ -77,7 +75,6 @@ def main() -> None:
         ("serve", serve_bench),
         ("dvfs", dvfs_bench),
         ("synergy", synergy_bench),
-        ("roofline", roofline_bench),
         ("kernels", kernels_bench),
     ]
     failures = 0

@@ -1,8 +1,8 @@
 """Co-location executor demo: REAL JAX training jobs time-sharing one mesh.
 
-This is the TPU-native analogue of the paper's GPU context-switch sharing
-(DESIGN.md §2): two reduced-config LM jobs run interleaved, step by step,
-inside one process.  The early-stage profiler measures each job's step time
+This is the TPU-native analogue of the paper's GPU context-switch sharing:
+two reduced-config LM jobs run interleaved, step by step, inside one
+process.  The early-stage profiler measures each job's step time
 solo and co-located — the measured inflation is what EaCO's observation
 phase would feed into its history H.
 

@@ -5,9 +5,7 @@ time, compute duty cycle, HBM footprint, per-SKU speedups, and Amdahl
 scaling coefficient are all derived from the analytic roofline
 (``roofline.analysis.analytic_roofline``) on the production mesh — no
 lowering, no compilation, no accelerator, so the pipeline runs in CI in
-milliseconds.  Where a compiled dry-run artifact exists for a cell its
-measured roofline is the better source; the analytic terms are calibrated
-against those artifacts and keep the same bottleneck classification.
+milliseconds.
 
 Derivation, per family (shape ``train_4k``, 256-chip single-pod mesh):
 

@@ -2,7 +2,7 @@
 
 The scheduler treats sub-meshes like the paper treats GPU sets: a job gets
 a contiguous slice of the device grid; FindCandidates operates on sub-mesh
-granularity.  Complements the temporal stepper (DESIGN.md §2): spatial for
+granularity.  Complements the temporal stepper: spatial for
 jobs with incompatible memory footprints, temporal for complementary duty
 cycles.
 """

@@ -1,5 +1,5 @@
 """Temporal co-location executor: the TPU-native analogue of GPU
-hardware context switching (DESIGN.md §2).
+hardware context switching.
 
 A TPU core runs one XLA program at a time — there is no driver-level
 time-slicing — so EaCO's mechanism maps to *step-granular round-robin*:

@@ -3,9 +3,9 @@
 Every assigned architecture is an :class:`ArchConfig` instance registered in
 :data:`REGISTRY`.  The config fully determines the model family (dense / moe /
 ssm / hybrid / enc-dec), the attention flavour (GQA / MLA / sliding-window),
-and the parallelism-relevant geometry.  ``input_specs`` builds the
-``jax.ShapeDtypeStruct`` stand-ins used by the multi-pod dry-run (no device
-allocation ever happens for the full-size configs).
+and the parallelism-relevant geometry.  ``input_specs`` builds
+``jax.ShapeDtypeStruct`` stand-ins for a cell of the shape grid (no device
+allocation happens for the full-size configs).
 """
 
 from __future__ import annotations
@@ -75,10 +75,6 @@ class MoEConfig:
     # Capacity factor for the dropping dispatch (tokens per expert).
     capacity_factor: float = 1.25
     router_aux_weight: float = 1e-2
-    # Wide expert parallelism (§Perf): shard experts over BOTH mesh axes on
-    # the E dim (1 expert per chip at E=256 on 256 chips) — expert weights
-    # never all-gather and expert grads never cross-reduce.
-    ep_wide: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -125,9 +121,8 @@ class ArchConfig:
     mtp_depth: int = 0
     # training/runtime knobs
     dtype: str = "bfloat16"
-    kv_cache_dtype: str = "bf16"  # bf16 | int8 (quantized serving cache)
     optimizer: str = "adamw"  # adamw | adafactor (giant archs)
-    remat: str = "full"  # none | full | dots
+    remat: str = "full"  # full (recompute each scanned block) | none
     zero: bool = True  # shard optimizer state over the data axis too
     fsdp: bool = False  # additionally shard the *weights* over data (giant archs)
     tie_embeddings: bool = False
@@ -173,7 +168,7 @@ class ArchConfig:
         if shape.name == "long_500k" and not self.is_subquadratic:
             return False, (
                 "long_500k needs sub-quadratic attention; "
-                f"{self.name} uses full attention (see DESIGN.md)"
+                f"{self.name} uses full attention, quadratic in the length"
             )
         return True, ""
 
@@ -344,7 +339,7 @@ def smoke_config(cfg: ArchConfig) -> ArchConfig:
 
 
 # ---------------------------------------------------------------------------
-# input_specs: ShapeDtypeStruct stand-ins for the dry-run
+# input_specs: ShapeDtypeStruct stand-ins for a cell of the shape grid
 # ---------------------------------------------------------------------------
 
 

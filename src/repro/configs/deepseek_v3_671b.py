@@ -3,8 +3,8 @@
 [arXiv:2412.19437; hf:deepseek-ai/DeepSeek-V3]  61L d_model=7168 128H
 d_ff(dense)=18432 d_ff(expert)=2048 vocab=129280; MLA q_lora=1536
 kv_lora=512 nope=128 rope=64 v=128; 1 shared + 256 routed top-8; first 3
-layers dense; 1 MTP depth.  Uses adafactor so the optimizer state fits the
-assigned meshes (see DESIGN.md §5 and EXPERIMENTS.md §Dry-run).
+layers dense; 1 MTP depth.  Uses adafactor: AdamW's 8 bytes a parameter
+of fp32 state would not fit.
 """
 
 from repro.configs.base import ArchConfig, MLAConfig, MoEConfig, register

@@ -4,7 +4,7 @@
 64H (GQA kv=8) d_ff=24576 vocab=65536; 16 experts top-2 on alternating
 layers; layer pattern per 8-block: [attn, ssm x7] (1:7 interleave).
 KV cache exists only in the 9 attention layers => ``long_500k`` RUNS.
-Uses adafactor for optimizer-state fit (DESIGN.md §5).
+Uses adafactor: AdamW's 8 bytes a parameter of fp32 state would not fit.
 """
 
 from repro.configs.base import ArchConfig, MoEConfig, SSMConfig, register
@@ -38,6 +38,6 @@ CONFIG = register(
         optimizer="adafactor",
         fsdp=True,
         remat="full",
-        notes="SSD used for the Mamba layers (TPU-native chunked scan; DESIGN.md).",
+        notes="SSD used for the Mamba layers (TPU-native chunked scan).",
     )
 )

@@ -5,7 +5,7 @@
                   when the device is not a TPU),
   * "interpret" — Pallas interpret mode (CPU callers pass it explicitly),
   * "xla"       — the pure-jnp production fallback in ``repro.models`` /
-                  ``repro.kernels.ref`` (what the dry-run lowers).
+                  ``repro.kernels.ref``.
 """
 
 from __future__ import annotations

@@ -1,8 +1,9 @@
 """Mesh construction.
 
 Every mesh is built by a FUNCTION (not a module constant) so importing
-this module never touches jax device state — the dry-run must set
-``XLA_FLAGS`` before anything initializes the backend.
+this module never touches jax device state.  The mesh's axes are
+``("data", "model")``: every layer shards the batch over ``"data"`` and
+weights and heads over ``"model"``, by those names.
 """
 
 from __future__ import annotations
@@ -22,17 +23,6 @@ def _make_mesh(shape, axes, devices=None) -> jax.sharding.Mesh:
     )
 
 
-def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
-    """The dry-run's production meshes.
-
-    single-pod: (16, 16) = 256 chips, axes ("data", "model")
-    multi-pod:  (2, 16, 16) = 512 chips, axes ("pod", "data", "model")
-    """
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
-
-
 def host_mesh_shape(n_devices: int) -> Tuple[int, int]:
     """The squarest ``(data, model)`` split of ``n_devices`` with
     ``data >= model``: 1 -> (1, 1), 4 -> (2, 2), 8 -> (4, 2)."""
@@ -46,7 +36,3 @@ def make_host_mesh(devices=None) -> jax.sharding.Mesh:
     devices = list(jax.devices() if devices is None else devices)
     return _make_mesh(host_mesh_shape(len(devices)), ("data", "model"), devices)
 
-
-def batch_axes_of(mesh: jax.sharding.Mesh) -> Tuple[str, ...]:
-    """Axes that carry the batch dimension (pod composes with data)."""
-    return ("pod", "data") if "pod" in mesh.axis_names else ("data",)

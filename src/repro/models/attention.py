@@ -8,9 +8,9 @@ Each layer exposes:
   ``*_init_cache``    abstract/zero cache construction.
 
 Caches are dicts of arrays whose sequence axis is sharded over the ``model``
-mesh axis in the serving configs (the KV cache is by far the largest decode
-buffer; sharding it over seq keeps the per-chip HBM bounded while the
-collectives stay tiny — see DESIGN.md §5).
+mesh axis in the serving configs: the KV cache is by far the largest decode
+buffer, and sharding it over seq keeps the per-chip HBM bounded while the
+decode step's collectives stay as small as its one-token query.
 """
 
 from __future__ import annotations
@@ -84,7 +84,6 @@ def gqa_forward(
     positions: jax.Array,
     q_chunk: int = 1024,
     mesh: Optional[jax.sharding.Mesh] = None,
-    batch_axes: Tuple[str, ...] = ("data",),
 ) -> jax.Array:
     """Causal self-attention over a full sequence (train / prefill); the
     path is ``causal_attention``'s choice."""
@@ -97,25 +96,8 @@ def gqa_forward(
         window=cfg.sliding_window,
         q_chunk=q_chunk,
         mesh=mesh,
-        batch_axes=batch_axes,
     )
     return jnp.einsum("bsh,hd->bsd", o.reshape(B, S, -1), p["wo"])
-
-
-def quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Per-(token, head) symmetric int8 quantization of K/V entries.
-
-    Halves decode HBM traffic and cache footprint (§Perf int8-KV
-    optimization); scales are fp32 at 1/head_dim the volume (<4% overhead).
-    """
-    amax = jnp.maximum(jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1), 1e-8)
-    scale = amax / 127.0
-    q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale[..., None]), -127, 127)
-    return q.astype(jnp.int8), scale.astype(jnp.float32)
-
-
-def dequantize_kv(q: jax.Array, scale: jax.Array, dtype=jnp.bfloat16) -> jax.Array:
-    return (q.astype(jnp.float32) * scale[..., None]).astype(dtype)
 
 
 def gqa_make_cache(
@@ -125,25 +107,14 @@ def gqa_make_cache(
     if cfg.sliding_window is not None:
         max_len = min(max_len, cfg.sliding_window)  # ring buffer
     shape = (batch, max_len, Hkv, hd)
-    if cfg.kv_cache_dtype == "int8":
-        return {
-            "k": jnp.zeros(shape, jnp.int8),
-            "v": jnp.zeros(shape, jnp.int8),
-            "k_scale": jnp.zeros(shape[:-1], jnp.float32),
-            "v_scale": jnp.zeros(shape[:-1], jnp.float32),
-        }
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-def gqa_cache_spec(cfg: ArchConfig, batch_axes: Any) -> Dict[str, Any]:
+def gqa_cache_spec(cfg: ArchConfig) -> Dict[str, Any]:
     from jax.sharding import PartitionSpec as P
 
-    spec = P(batch_axes, "model", None, None)
-    out = {"k": spec, "v": spec}
-    if cfg.kv_cache_dtype == "int8":
-        out["k_scale"] = P(batch_axes, "model", None)
-        out["v_scale"] = P(batch_axes, "model", None)
-    return out
+    spec = P("data", "model", None, None)
+    return {"k": spec, "v": spec}
 
 
 def gqa_decode(
@@ -168,23 +139,9 @@ def gqa_decode(
         v_new = shard_fn(v_new, ("batch", None, None, None))
     W = cache["k"].shape[1]
     slot = cache_len % W if cfg.sliding_window is not None else cache_len
-    new_cache: Cache = {}
-    if cfg.kv_cache_dtype == "int8":
-        kq, ks = quantize_kv(k_new)
-        vq, vs = quantize_kv(v_new)
-        kc = jax.lax.dynamic_update_slice_in_dim(cache["k"], kq, slot, axis=1)
-        vc = jax.lax.dynamic_update_slice_in_dim(cache["v"], vq, slot, axis=1)
-        ksc = jax.lax.dynamic_update_slice_in_dim(cache["k_scale"], ks, slot, axis=1)
-        vsc = jax.lax.dynamic_update_slice_in_dim(cache["v_scale"], vs, slot, axis=1)
-        new_cache = {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc}
-        # on TPU the dequant fuses into the attention matmul stream (HBM
-        # reads stay int8); here it materializes for the XLA fallback
-        k = dequantize_kv(kc, ksc, k_new.dtype)
-        v = dequantize_kv(vc, vsc, v_new.dtype)
-    else:
-        k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, slot, axis=1)
-        v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, slot, axis=1)
-        new_cache = {"k": k, "v": v}
+    k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, slot, axis=1)
+    v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, slot, axis=1)
+    new_cache: Cache = {"k": k, "v": v}
     if shard_fn is not None:
         k = shard_fn(k, ("batch", "model", None, None))
         v = shard_fn(v, ("batch", "model", None, None))
@@ -343,10 +300,10 @@ def mla_make_cache(
     }
 
 
-def mla_cache_spec(cfg: ArchConfig, batch_axes: Any) -> Dict[str, Any]:
+def mla_cache_spec(cfg: ArchConfig) -> Dict[str, Any]:
     from jax.sharding import PartitionSpec as P
 
-    return {"ckv": P(batch_axes, "model", None), "kr": P(batch_axes, "model", None)}
+    return {"ckv": P("data", "model", None), "kr": P("data", "model", None)}
 
 
 def mla_decode(

@@ -1,10 +1,11 @@
 """Shared model components: norms, RoPE, embeddings, MLPs, chunked attention.
 
 Everything is written as plain functions over parameter dicts so the same
-code path serves (a) smoke tests on 1 CPU device, (b) the 512-chip dry-run
-under pjit, and (c) real training.  On a TPU causal self-attention runs the
-splash flash kernel (``causal_attention``); elsewhere attention is *chunked
-over queries* (lax.scan) so no S x S score tensor is ever materialized.
+code path serves smoke tests on one CPU device and training on one chip or
+sharded over a host's ``("data", "model")`` mesh.  On a TPU causal
+self-attention runs the splash flash kernel (``causal_attention``);
+elsewhere attention is *chunked over queries* (lax.scan) so no S x S score
+tensor is ever materialized.
 """
 
 from __future__ import annotations
@@ -374,13 +375,12 @@ def causal_attention(
     window: Optional[int] = None,
     q_chunk: int = 1024,
     mesh: Optional[jax.sharding.Mesh] = None,
-    batch_axes: Tuple[str, ...] = ("data",),
 ) -> jax.Array:
     """Causal self-attention over a full sequence (train / prefill), with
     keys older than ``window`` masked.
 
     Takes the flash kernel where ``attention_path`` allows, under a mesh
-    through ``shard_map``: batch over ``batch_axes``, heads over ``model``
+    through ``shard_map``: batch over ``data``, heads over ``model``
     (query heads of one group stay with their KV head, so nothing moves
     between chips), under the ``attention_core`` scope that the XLA path's
     ``banded_attention`` / ``attention`` carry.  Each trace counts its path
@@ -388,7 +388,7 @@ def causal_attention(
     """
     shards = (1, 1)
     if mesh is not None:
-        shards = (math.prod(mesh.shape[a] for a in batch_axes), mesh.shape["model"])
+        shards = (mesh.shape["data"], mesh.shape["model"])
     path, why = attention_path(_platform(mesh), q.shape, k.shape, v.shape, shards)
     ATTENTION_PATHS[path, why] += 1
     print(
@@ -403,8 +403,7 @@ def causal_attention(
         return attention(q, k, v, causal=True, q_chunk=q_chunk)
     flash = functools.partial(_flash, window=window)
     if mesh is not None:
-        bspec = batch_axes if len(batch_axes) > 1 else batch_axes[0]
-        spec = P(bspec, None, "model", None)
+        spec = P("data", None, "model", None)
         flash = jax.shard_map(
             flash, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False
         )

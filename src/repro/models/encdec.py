@@ -39,13 +39,11 @@ class EncDecModel:
         self,
         cfg: ArchConfig,
         mesh: Optional[jax.sharding.Mesh] = None,
-        batch_axes: Tuple[str, ...] = ("data",),
         q_chunk: int = 1024,
     ):
         assert cfg.enc_dec
         self.cfg = cfg
         self.mesh = mesh
-        self.batch_axes = batch_axes
         self.q_chunk = q_chunk
 
     # -- defs --------------------------------------------------------------
@@ -93,19 +91,15 @@ class EncDecModel:
     def _constrain(self, x):
         if self.mesh is None:
             return x
-        spec = self.batch_axes if len(self.batch_axes) > 1 else self.batch_axes[0]
         return jax.lax.with_sharding_constraint(
-            x, NamedSharding(self.mesh, P(spec, None, None))
+            x, NamedSharding(self.mesh, P("data", None, None))
         )
 
     def _decode_shard_fn(self, batch: int):
         if self.mesh is None:
             return None
-        n_data = 1
-        for a in self.batch_axes:
-            n_data *= self.mesh.shape[a]
-        baxes = self.batch_axes if len(self.batch_axes) > 1 else self.batch_axes[0]
-        b_entry = baxes if (batch % n_data == 0 and batch > 1) else None
+        n_data = self.mesh.shape["data"]
+        b_entry = "data" if (batch % n_data == 0 and batch > 1) else None
 
         def shard(t, spec):
             entries = tuple(b_entry if e == "batch" else e for e in spec)
@@ -145,9 +139,7 @@ class EncDecModel:
     def _dec_block(self, p, x, positions, memory):
         cfg = self.cfg
         h = rmsnorm(p["norm1"], x)
-        x = x + attn.gqa_forward(
-            p["mixer"], cfg, h, positions, self.q_chunk, self.mesh, self.batch_axes
-        )
+        x = x + attn.gqa_forward(p["mixer"], cfg, h, positions, self.q_chunk, self.mesh)
         h = rmsnorm(p["norm_x"], x)
         mem_kv = attn.cross_memory_kv(p["cross"], cfg, memory)
         x = x + attn.cross_forward(p["cross"], cfg, h, mem_kv, self.q_chunk)
@@ -193,12 +185,11 @@ class EncDecModel:
         }
 
     def cache_specs(self) -> Dict[str, Any]:
-        baxes = self.batch_axes if len(self.batch_axes) > 1 else self.batch_axes[0]
-        kv = P(None, baxes, "model", None, None)
+        kv = P(None, "data", "model", None, None)
         return {
             "self": {s: kv for s in ("k", "v")},
-            "cross_k": P(None, baxes, None, "model" if self.cfg.num_kv_heads % 16 == 0 else None, None),
-            "cross_v": P(None, baxes, None, "model" if self.cfg.num_kv_heads % 16 == 0 else None, None),
+            "cross_k": P(None, "data", None, "model" if self.cfg.num_kv_heads % 16 == 0 else None, None),
+            "cross_v": P(None, "data", None, "model" if self.cfg.num_kv_heads % 16 == 0 else None, None),
         }
 
     def prefill(

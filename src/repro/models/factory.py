@@ -2,7 +2,7 @@
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 
@@ -14,9 +14,8 @@ from repro.models.transformer import Model
 def build_model(
     cfg: ArchConfig,
     mesh: Optional[jax.sharding.Mesh] = None,
-    batch_axes: Tuple[str, ...] = ("data",),
     q_chunk: int = 1024,
 ):
     if cfg.enc_dec:
-        return EncDecModel(cfg, mesh, batch_axes, q_chunk)
-    return Model(cfg, mesh, batch_axes, q_chunk)
+        return EncDecModel(cfg, mesh, q_chunk)
+    return Model(cfg, mesh, q_chunk)

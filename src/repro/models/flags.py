@@ -1,9 +1,11 @@
 """Global model-construction flags.
 
 ``FULL_UNROLL``: XLA's ``cost_analysis()`` counts a while-loop body ONCE,
-ignoring the trip count, so rooflines derived from scan-structured HLO
-undercount FLOPs/bytes by ~L.  The dry-run therefore builds with every scan
-fully unrolled (``lax.scan(..., unroll=length)`` eliminates the loop).
+ignoring the trip count, so costs read from scan-structured HLO undercount
+FLOPs/bytes by ~L.  A program whose cost is read is therefore built with
+every scan fully unrolled (``lax.scan(..., unroll=length)`` eliminates the
+loop): ``tests/test_tpu_compile.py`` does so for the SSD scan's bytes and
+``chipbench/tests/test_flops.py`` for the forward's matrix products.
 Training/serving keep the rolled form (small HLO, fast compiles).
 
 Use the ``scan`` wrapper below at every scan site so one flag flips all of
@@ -32,7 +34,7 @@ def full_unroll(enabled: bool = True):
 
 
 def scan(body, init, xs, length: int | None = None, unroll: int = 1):
-    """lax.scan honoring FULL_UNROLL (dry-run cost-accounting mode);
+    """lax.scan honoring FULL_UNROLL (cost-accounting mode);
     ``unroll`` is the iterations per trip of the rolled form."""
     if length is None:
         length = jax.tree.leaves(xs)[0].shape[0]

@@ -251,13 +251,13 @@ def mamba_make_cache(cfg: ArchConfig, batch: int, dtype=jnp.bfloat16) -> Cache:
     }
 
 
-def mamba_cache_spec(cfg: ArchConfig, batch_axes: Any) -> Dict[str, Any]:
+def mamba_cache_spec(cfg: ArchConfig) -> Dict[str, Any]:
     from jax.sharding import PartitionSpec as P
 
     return {
-        "h": P(batch_axes, "model", None, None),
-        "conv_x": P(batch_axes, None, "model"),
-        "conv_bc": P(batch_axes, None, None),
+        "h": P("data", "model", None, None),
+        "conv_x": P("data", None, "model"),
+        "conv_bc": P("data", None, None),
     }
 
 

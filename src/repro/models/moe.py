@@ -19,7 +19,7 @@ Two dispatch implementations share one router:
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,15 +34,11 @@ def moe_def(cfg: ArchConfig) -> Dict[str, ParamDef]:
     m = cfg.moe
     assert m is not None
     d, f, E = cfg.d_model, m.d_ff_expert, m.num_experts
-    # ep_wide: experts sharded across BOTH mesh axes on the E dim — weights
-    # are fully resident where their tokens are routed (no FSDP gathers, no
-    # cross-device grad reduction for expert params).
-    espec = ("model", "data") if m.ep_wide else "model"
     defs: Dict[str, ParamDef] = {
         "router": ParamDef((d, E), (None, None), normal_init(0.02), jnp.float32),
-        "gate": ParamDef((E, d, f), (espec, None, None), fan_in_init()),
-        "up": ParamDef((E, d, f), (espec, None, None), fan_in_init()),
-        "down": ParamDef((E, f, d), (espec, None, None), fan_in_init()),
+        "gate": ParamDef((E, d, f), ("model", None, None), fan_in_init()),
+        "up": ParamDef((E, d, f), ("model", None, None), fan_in_init()),
+        "down": ParamDef((E, f, d), ("model", None, None), fan_in_init()),
     }
     if m.num_shared_experts:
         defs["shared"] = swiglu_def(d, m.num_shared_experts * f)
@@ -149,15 +145,12 @@ def moe_forward(
     cfg: ArchConfig,
     x: jax.Array,
     mesh: jax.sharding.Mesh,
-    batch_axes: Tuple[str, ...],
 ) -> Tuple[jax.Array, jax.Array]:
     """Expert-parallel MoE forward. x: (B, S, D) sharded on batch."""
     m = cfg.moe
     B, S, D = x.shape
     E = m.num_experts
-    n_shards = 1
-    for a in batch_axes:
-        n_shards *= mesh.shape[a]
+    n_shards = mesh.shape["data"]
     if B % n_shards:
         # tiny-token path (e.g. batch-1 long-context decode): the dense
         # one-hot dispatch is cheaper than any resharding at this size.
@@ -171,8 +164,6 @@ def moe_forward(
     w = (w / jnp.sum(w, axis=-1, keepdims=True)).astype(x.dtype)
     aux = aux_load_balance_loss(probs, idx, E)
 
-    bspec = batch_axes if len(batch_axes) > 1 else batch_axes[0]
-
     def dispatch(xt_l, idx_l):
         buf, dest = _local_dispatch(xt_l, idx_l, C, E)
         return buf[None], dest[None]  # add shard dim
@@ -180,20 +171,11 @@ def moe_forward(
     buf, dest = jax.shard_map(
         dispatch,
         mesh=mesh,
-        in_specs=(P(bspec, None), P(bspec, None)),
-        out_specs=(P(bspec, None, None), P(bspec, None)),
+        in_specs=(P("data", None), P("data", None)),
+        out_specs=(P("data", None, None), P("data", None)),
     )(xt, idx)
     # buf: (shards, E*C+1, D) sharded on dim0 -> expert-major (E, shards, C, D)
-    if m.ep_wide:
-        # experts span both mesh axes; only a leftover pod axis (if any)
-        # shards the source dim
-        e_entry = ("model", "data")
-        s_entry = tuple(a for a in batch_axes if a not in e_entry) or None
-        if isinstance(s_entry, tuple) and len(s_entry) == 1:
-            s_entry = s_entry[0]
-        grid_spec = P(e_entry, s_entry, None, None)
-    else:
-        grid_spec = P("model", bspec, None, None)
+    grid_spec = P("model", "data", None, None)
     grid = buf[:, : E * C, :].reshape(n_shards, E, C, D)
     grid = jnp.swapaxes(grid, 0, 1)
     grid = jax.lax.with_sharding_constraint(
@@ -207,7 +189,7 @@ def moe_forward(
     )
     y = jnp.swapaxes(y, 0, 1).reshape(n_shards, E * C, D)
     y = jax.lax.with_sharding_constraint(
-        y, jax.sharding.NamedSharding(mesh, P(bspec, None, None))
+        y, jax.sharding.NamedSharding(mesh, P("data", None, None))
     )  # <- the return all-to-all
 
     def combine(y_l, dest_l, w_l):
@@ -219,8 +201,8 @@ def moe_forward(
     out = jax.shard_map(
         combine,
         mesh=mesh,
-        in_specs=(P(bspec, None, None), P(bspec, None), P(bspec, None)),
-        out_specs=P(bspec, None),
+        in_specs=(P("data", None, None), P("data", None), P("data", None)),
+        out_specs=P("data", None),
     )(y, dest, w)
     out = out.reshape(B, S, D)
     if m.num_shared_experts:

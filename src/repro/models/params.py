@@ -5,7 +5,7 @@ partition spec).  From one definition tree we derive, *congruently by
 construction*:
 
   * materialized parameters (``init``),
-  * abstract parameters for the dry-run (``abstract``),
+  * abstract parameters, shapes without allocation (``abstract``),
   * ``PartitionSpec`` trees for pjit in/out shardings (``specs``),
   * ZeRO-extended specs for optimizer state (``zero_specs``).
 
@@ -13,7 +13,6 @@ Logical axis names used by the model zoo:
 
   ``model``  tensor-parallel axis (heads / d_ff / experts / vocab)
   ``data``   data-parallel axis (batch; optimizer state under ZeRO)
-  ``pod``    cross-pod data-parallel axis (multi-pod mesh only)
 """
 
 from __future__ import annotations
@@ -74,7 +73,7 @@ class ParamDef:
     """One parameter: shape, dtype, initializer and logical sharding spec.
 
     ``spec`` entries are logical axis names (``"model"`` / ``None``); the
-    ``data``/``pod`` axes are introduced only by the ZeRO transform.
+    ``data`` axis is introduced only by the ZeRO transform.
     """
 
     shape: Tuple[int, ...]
@@ -126,7 +125,7 @@ def init_params(defs: Any, key: jax.Array) -> Any:
 
 
 def abstract_params(defs: Any) -> Any:
-    """ShapeDtypeStruct tree for the dry-run (no allocation)."""
+    """ShapeDtypeStruct tree (no allocation)."""
     return _tree_map(lambda d: jax.ShapeDtypeStruct(d.shape, d.dtype), defs)
 
 
@@ -135,49 +134,33 @@ def partition_specs(defs: Any) -> Any:
     return _tree_map(lambda d: P(*d.spec), defs)
 
 
-def zero_specs(defs: Any, data_axes: Tuple[str, ...], data_size: int) -> Any:
+def zero_specs(defs: Any, data_size: int) -> Any:
     """ZeRO/FSDP specs: additionally shard the largest unsharded, divisible
-    axis over the data axes.  Params whose spec already uses a data axis are
-    returned unchanged (idempotent — FSDP'd weights feed straight through)."""
+    axis over the ``"data"`` mesh axis.  Params whose spec already uses it
+    are returned unchanged (idempotent — FSDP'd weights feed straight through)."""
 
     def _zero(d: ParamDef) -> P:
         spec = list(d.spec)
-        for s in spec:
-            entries = s if isinstance(s, tuple) else (s,)
-            if any(e in data_axes for e in entries if e):
-                return P(*spec)  # already data-sharded
+        if "data" in spec:
+            return P(*spec)  # already data-sharded
         # pick the largest dim that is unsharded and divisible
         best, best_dim = -1, -1
         for i, (dim, s) in enumerate(zip(d.shape, spec)):
             if s is None and dim % data_size == 0 and dim > best_dim:
                 best, best_dim = i, dim
         if best >= 0:
-            spec[best] = data_axes if len(data_axes) > 1 else data_axes[0]
+            spec[best] = "data"
         return P(*spec)
 
     return _tree_map(_zero, defs)
 
 
-def fsdp_param_specs(defs: Any, data_axes: Tuple[str, ...], data_size: int) -> Any:
+def fsdp_param_specs(defs: Any, data_size: int) -> Any:
     """Weight specs with data-axis sharding on the largest free dim.
 
     XLA all-gathers each scanned layer's weights on use and reduce-scatters
     its gradients — ZeRO-3 semantics expressed purely through shardings."""
-    return zero_specs(defs, data_axes, data_size)
-
-
-def strip_model_axis(defs: Any) -> Any:
-    """Remove tensor-parallel ("model") sharding from every param spec.
-
-    Used by the ZeRO-3 pure-DP layout (§Perf): weights become unsharded in
-    the TP sense, then ``zero_specs`` over BOTH mesh axes distributes them
-    across all chips; XLA gathers each scanned layer's weights on use."""
-
-    def _strip(d: ParamDef) -> ParamDef:
-        spec = tuple(None if s == "model" else s for s in d.spec)
-        return ParamDef(shape=d.shape, spec=spec, init=d.init, dtype=d.dtype)
-
-    return _tree_map(_strip, defs)
+    return zero_specs(defs, data_size)
 
 
 def param_bytes(defs: Any) -> int:

@@ -7,8 +7,7 @@ One :class:`Model` drives every assigned decoder-only architecture:
     of (ssm x4+attn+ssm x3 with alternating dense/MoE channel mixers);
   * each group is a single ``lax.scan`` over stacked parameters with
     ``jax.checkpoint`` (remat) around the block body — keeps the HLO small
-    enough that 512-device SPMD compiles stay fast and activation memory is
-    O(layers x checkpoint inputs);
+    and activation memory O(layers x checkpoint inputs);
   * decode threads a per-group stacked cache through the same scan.
 
 The class exposes ``loss`` (train), ``prefill`` and ``decode_step`` (serve),
@@ -18,14 +17,14 @@ plus congruent parameter/cache PartitionSpec trees for pjit.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.configs.base import ArchConfig, ShapeSpec
+from repro.configs.base import ArchConfig
 from repro.models import attention as attn
 from repro.models import flags
 from repro.models import mamba as mb
@@ -82,12 +81,10 @@ class Model:
         self,
         cfg: ArchConfig,
         mesh: Optional[jax.sharding.Mesh] = None,
-        batch_axes: Tuple[str, ...] = ("data",),
         q_chunk: int = 1024,
     ):
         self.cfg = cfg
         self.mesh = mesh
-        self.batch_axes = batch_axes
         self.q_chunk = q_chunk
         self.groups = _layer_groups(cfg)
 
@@ -158,9 +155,7 @@ class Model:
             if cfg.attention == "mla":
                 h = attn.mla_forward(p["mixer"], cfg, h, positions, self.q_chunk)
             else:
-                h = attn.gqa_forward(
-                    p["mixer"], cfg, h, positions, self.q_chunk, self.mesh, self.batch_axes
-                )
+                h = attn.gqa_forward(p["mixer"], cfg, h, positions, self.q_chunk, self.mesh)
         else:
             h = mb.mamba_forward(p["mixer"], cfg, h)
         x = x + h
@@ -168,9 +163,7 @@ class Model:
             h = rmsnorm(p["norm2"], x)
             if spec.channel == "moe":
                 if self.mesh is not None:
-                    h, aux = moe_mod.moe_forward(
-                        p["channel"], cfg, h, self.mesh, self.batch_axes
-                    )
+                    h, aux = moe_mod.moe_forward(p["channel"], cfg, h, self.mesh)
                 else:
                     h, aux = moe_mod.moe_forward_onehot(p["channel"], cfg, h)
             else:
@@ -182,23 +175,17 @@ class Model:
     def _constrain(self, x: jax.Array) -> jax.Array:
         if self.mesh is None:
             return x
-        spec = (
-            self.batch_axes if len(self.batch_axes) > 1 else self.batch_axes[0]
-        )
         return jax.lax.with_sharding_constraint(
-            x, NamedSharding(self.mesh, P(spec, None, None))
+            x, NamedSharding(self.mesh, P("data", None, None))
         )
 
     def _decode_shard_fn(self, batch: int):
         """Sharding-constraint callback for decode attention ("batch" in a
-        spec tuple maps to the batch axes, dropped when indivisible)."""
+        spec tuple maps to the data axis, dropped when indivisible)."""
         if self.mesh is None:
             return None
-        n_data = 1
-        for a in self.batch_axes:
-            n_data *= self.mesh.shape[a]
-        baxes = self.batch_axes if len(self.batch_axes) > 1 else self.batch_axes[0]
-        b_entry = baxes if (batch % n_data == 0 and batch > 1) else None
+        n_data = self.mesh.shape["data"]
+        b_entry = "data" if (batch % n_data == 0 and batch > 1) else None
 
         def shard(t, spec):
             entries = tuple(b_entry if e == "batch" else e for e in spec)
@@ -209,12 +196,6 @@ class Model:
         return shard
 
     def _remat(self, body):
-        if self.cfg.remat == "dots":
-            # selective: keep matmul outputs, recompute elementwise — trades
-            # HBM for the recompute FLOPs (see EXPERIMENTS.md §Perf)
-            return jax.checkpoint(
-                body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-            )
         if self.cfg.remat != "none":
             return jax.checkpoint(body)  # full remat per scanned block
         return body
@@ -319,19 +300,18 @@ class Model:
 
     def cache_specs(self) -> Dict[str, Any]:
         cfg = self.cfg
-        baxes = self.batch_axes if len(self.batch_axes) > 1 else self.batch_axes[0]
         out: Dict[str, Any] = {}
         for name, n, layers in self.groups:
             per_layer = {}
             for j, spec in enumerate(layers):
                 if spec.mixer == "attn":
                     s = (
-                        attn.mla_cache_spec(cfg, baxes)
+                        attn.mla_cache_spec(cfg)
                         if cfg.attention == "mla"
-                        else attn.gqa_cache_spec(cfg, baxes)
+                        else attn.gqa_cache_spec(cfg)
                     )
                 else:
-                    s = mb.mamba_cache_spec(cfg, baxes)
+                    s = mb.mamba_cache_spec(cfg)
                 per_layer[f"l{j}"] = s
             out[name] = jax.tree.map(
                 lambda sp: P(*((None,) + tuple(sp))),
@@ -360,7 +340,7 @@ class Model:
             h = rmsnorm(p["norm2"], x)
             if spec.channel == "moe":
                 h, _ = (
-                    moe_mod.moe_forward(p["channel"], cfg, h, self.mesh, self.batch_axes)
+                    moe_mod.moe_forward(p["channel"], cfg, h, self.mesh)
                     if self.mesh is not None
                     else moe_mod.moe_forward_onehot(p["channel"], cfg, h)
                 )
@@ -460,17 +440,11 @@ class Model:
                 "kr": jax.lax.dynamic_update_slice_in_dim(c["kr"], kr, 0, axis=1),
             }
         else:
-            out = attn.gqa_forward(
-                p["mixer"], cfg, h, positions, self.q_chunk, self.mesh, self.batch_axes
-            )
+            out = attn.gqa_forward(p["mixer"], cfg, h, positions, self.q_chunk, self.mesh)
             _, k, v = attn._gqa_qkv(p["mixer"], cfg, h, positions)
             c = attn.gqa_make_cache(cfg, B, max_len, dtype=k.dtype)
             W = c["k"].shape[1]
             parts = {"k": k, "v": v}
-            if cfg.kv_cache_dtype == "int8":
-                kq, ks = attn.quantize_kv(k)
-                vq, vs = attn.quantize_kv(v)
-                parts = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
             if cfg.sliding_window is not None and S >= W:
                 # keep the last W entries, rolled so slot = pos % W
                 idx = (S - W + jnp.arange(W)) % W
@@ -488,7 +462,7 @@ class Model:
             hh = rmsnorm(p["norm2"], x)
             if spec.channel == "moe":
                 hh, _ = (
-                    moe_mod.moe_forward(p["channel"], cfg, hh, self.mesh, self.batch_axes)
+                    moe_mod.moe_forward(p["channel"], cfg, hh, self.mesh)
                     if self.mesh is not None
                     else moe_mod.moe_forward_onehot(p["channel"], cfg, hh)
                 )

@@ -6,8 +6,7 @@ ZeRO sharding specs (``models.params.zero_specs``) apply directly.
 AdamW keeps fp32 ``m``/``v`` (the standard mixed-precision recipe).
 Adafactor factors the second moment for >=2-D parameters (row/col
 accumulators) and skips momentum — the optimizer-state footprint drops from
-8 bytes/param to ~0, which is what lets the 398B/671B train cells fit the
-assigned v5e meshes (DESIGN.md §5).
+8 bytes/param to ~0, which is what lets the 398B/671B configurations fit.
 """
 
 from __future__ import annotations

@@ -1,19 +1,15 @@
 """Gradient compression with error feedback (int8 quantization).
 
-Used for the cross-pod (DCN) gradient reduction in two places:
+Models a compressed cross-host (DCN) gradient reduction two ways:
 
-  * numerically, inside the train step (optional): gradients are quantized /
-    dequantized with an error-feedback buffer before the optimizer update,
-    so training dynamics match what a compressed DCN all-reduce would
-    produce;
-  * analytically, by the cluster simulator's communication model, which
-    charges DCN bytes at ``bits/16`` of the bf16 volume when compression is
-    enabled.
+  * numerically: gradients are quantized / dequantized with an
+    error-feedback buffer (``compress_grads``), so training dynamics match
+    what a compressed all-reduce would produce;
+  * analytically: ``compressed_bytes`` charges DCN bytes at ``bits/16`` of
+    the bf16 volume.
 
-The lowered dry-run HLO keeps the full-precision all-reduce (XLA's SPMD
-partitioner owns that collective); EXPERIMENTS.md §Perf reports the
-collective-bytes delta analytically.  This is recorded as a changed
-assumption in DESIGN.md.
+No train step calls it: a compiled program keeps the full-precision
+all-reduce, which XLA's SPMD partitioner owns.
 """
 
 from __future__ import annotations

@@ -1,8 +1,9 @@
 """Analytic roofline layer: hardware constants + per-step cost analysis.
 
 ``repro.roofline.hw`` pins the accelerator/host capacity constants;
-``repro.roofline.analysis`` turns an XLA cost analysis + compiled HLO into
-compute/memory/collective roofline terms (``analyze``) and derives the
+``repro.roofline.analysis`` derives compute/memory/collective roofline
+terms from the analytic cost model (``analytic_roofline``), counts the
+collectives of a compiled program (``parse_collectives``) and derives the
 Synergy-style host-resource demand of a training configuration
 (``analytic_host_profile``) — the source of the bridge families' host
 rows in ``repro.bridge.profiles.derive_host``.
@@ -12,6 +13,5 @@ from repro.roofline.analysis import (  # noqa: F401
     CollectiveStats,
     Roofline,
     analytic_host_profile,
-    analyze,
     parse_collectives,
 )

@@ -1,25 +1,26 @@
-"""Roofline analysis from compiled dry-run artifacts.
+"""Roofline terms, collective bytes and model FLOPs of a step.
 
-Three terms per (arch x shape x mesh), all **per-device seconds**:
+Three terms per (arch x shape x chips), all **per-device seconds**:
 
-  compute    = HLO_FLOPs / PEAK_FLOPS_BF16
-  memory     = HLO_bytes_accessed / HBM_BW
+  compute    = FLOPs / PEAK_FLOPS_BF16
+  memory     = bytes_accessed / HBM_BW
   collective = collective_bytes / ICI_BW
 
-``cost_analysis()`` provides per-device FLOPs and bytes.  Collective bytes
-are not in ``cost_analysis`` — we parse the *compiled* (post-SPMD) HLO text,
-build a symbol table of instruction result sizes, and sum **operand** sizes
-of every all-gather / all-reduce / reduce-scatter / all-to-all /
-collective-permute instruction (per the assignment).  Note ring all-reduce
-moves ~2x its operand bytes on the wire; we report raw operand bytes and
-apply the x2 only in the (documented) ``wire_bytes`` field.
+``analytic_roofline`` derives them from the analytic cost model, without
+compiling anything.  Collective bytes of a compiled program are not in
+``cost_analysis`` — ``parse_collectives`` reads the *compiled* (post-SPMD)
+HLO text, builds a symbol table of instruction result sizes, and sums
+**operand** sizes of every all-gather / all-reduce / reduce-scatter /
+all-to-all / collective-permute instruction.  Note ring all-reduce moves
+~2x its operand bytes on the wire; we report raw operand bytes and apply
+the x2 only in the (documented) ``wire_bytes`` field.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Tuple
 
 from repro.roofline import hw
 
@@ -143,42 +144,6 @@ class Roofline:
         )
 
 
-def analyze(
-    cost: Dict[str, float],
-    hlo_text: str,
-    model_flops_global: float,
-    num_chips: int,
-) -> Roofline:
-    """Roofline terms for one compiled step: per-device compute / memory /
-    collective seconds from the XLA cost analysis + HLO collective scan,
-    with the largest term named as the bottleneck."""
-    flops = float(cost.get("flops", 0.0))
-    nbytes = float(cost.get("bytes accessed", 0.0))
-    colls = parse_collectives(hlo_text)
-    compute_s = flops / hw.MODELLED_PEAK_FLOPS_BF16
-    memory_s = nbytes / hw.HBM_BW
-    collective_s = colls.total_operand_bytes / hw.ICI_BW
-    terms = {
-        "compute": compute_s,
-        "memory": memory_s,
-        "collective": collective_s,
-    }
-    bottleneck = max(terms, key=terms.get)
-    model_flops = model_flops_global / num_chips
-    return Roofline(
-        flops=flops,
-        bytes_accessed=nbytes,
-        collective_bytes=colls.total_operand_bytes,
-        collective_counts=colls.counts,
-        compute_s=compute_s,
-        memory_s=memory_s,
-        collective_s=collective_s,
-        bottleneck=bottleneck,
-        model_flops=model_flops,
-        useful_ratio=model_flops / max(flops, 1.0),
-    )
-
-
 def kv_cache_bytes(cfg, batch: int, seq_len: int) -> int:
     """Total decode-cache bytes across the cluster for one serving batch."""
     n_attn = sum(1 for i in range(cfg.num_layers) if cfg.layer_kind(i) == "attn")
@@ -272,9 +237,6 @@ def analytic_roofline(cfg, shape, num_chips: int, microbatches: int = 8) -> Roof
     This is the calibration bridge's fast path (``repro.bridge``): it derives
     per-family JobProfiles in microseconds, without lowering or compiling
     anything, so the pipeline runs in CI on machines without accelerators.
-    Where a dry-run artifact exists its measured roofline should be
-    preferred; the two agree on the bottleneck classification for every
-    artifact checked in under ``benchmarks/artifacts/dryrun``.
     """
     mf_global = model_flops_for_cell(cfg, shape)
     flops = mf_global / num_chips

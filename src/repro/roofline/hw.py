@@ -3,7 +3,7 @@
 ``PEAK_FLOPS_BF16_BY_KIND`` holds published per-chip peaks keyed by
 ``jax.Device.device_kind``; code that times real steps on a device takes its
 peak from there (``peak_flops_bf16``).  The ``MODELLED_*`` and remaining
-constants describe a TPU v5e for the analytic models (dry-run roofline,
+constants describe a TPU v5e for the analytic models (analytic roofline,
 calibration bridge, cluster power model), which run without a chip.
 """
 

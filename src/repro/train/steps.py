@@ -1,23 +1,23 @@
 """Train/serve step factories: jitted, sharded, donate-friendly.
 
 These bundles are the single source of truth for every entry point —
-the real trainer, the co-location stepper, and the multi-pod dry-run all
-call ``make_train_bundle`` / ``make_serve_bundle`` so the lowered HLO is
-identical across them.
+the trainer and server launchers, the co-location stepper and the
+benchmark all call ``make_train_bundle`` / ``make_serve_bundle`` so the
+lowered HLO is identical across them.  Under a mesh the batch is split over
+its ``"data"`` axis and the weights over ``"model"``.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.configs.base import ArchConfig, ShapeSpec, input_specs
+from repro.configs.base import ArchConfig
 from repro.models import params as pu
 from repro.models.factory import build_model
 from repro.optim.adamw import (
@@ -26,10 +26,6 @@ from repro.optim.adamw import (
     make_optimizer,
 )
 from repro.optim.schedules import cosine_with_warmup
-
-
-def _batch_spec(batch_axes: Tuple[str, ...]):
-    return batch_axes if len(batch_axes) > 1 else batch_axes[0]
 
 
 def _ns(mesh, spec):
@@ -60,48 +56,23 @@ class TrainBundle:
 def make_train_bundle(
     cfg: ArchConfig,
     mesh: Optional[jax.sharding.Mesh] = None,
-    batch_axes: Tuple[str, ...] = ("data",),
     opt_cfg: Optional[OptimizerConfig] = None,
     lr_schedule: Optional[Callable] = None,
     grad_clip: float = 1.0,
     q_chunk: int = 1024,
     microbatches: int = 1,
-    layout: str = "megatron",  # "megatron" (TP over model axis) | "zero3"
-    zero2_grads: bool = False,  # data-shard the fp32 grad accumulator (§Perf)
 ) -> TrainBundle:
-    if layout == "zero3" and mesh is not None:
-        # pure-DP ZeRO-3: batch over EVERY mesh axis; weights fully sharded
-        # across all chips and gathered per scanned layer (§Perf)
-        batch_axes = tuple(mesh.axis_names)
-    model = build_model(cfg, mesh, batch_axes, q_chunk=q_chunk)
+    model = build_model(cfg, mesh, q_chunk=q_chunk)
     opt_cfg = opt_cfg or OptimizerConfig(name=cfg.optimizer)
     optimizer = make_optimizer(opt_cfg)
     lr_schedule = lr_schedule or cosine_with_warmup(3e-4, 100, 10_000)
 
     defs = model.param_defs()
-    if layout == "zero3" and mesh is not None:
-        defs_for_specs = pu.strip_model_axis(defs)
-        n_all = mesh.size
-        param_specs = pu.fsdp_param_specs(defs_for_specs, batch_axes, n_all)
-    elif cfg.fsdp and mesh is not None:
-        defs_for_specs = defs
-        n_data = 1
-        for a in batch_axes:
-            n_data *= mesh.shape[a]
-        param_specs = pu.fsdp_param_specs(defs, batch_axes, n_data)
+    if cfg.fsdp and mesh is not None:
+        param_specs = pu.fsdp_param_specs(defs, mesh.shape["data"])
     else:
-        defs_for_specs = defs
         param_specs = pu.partition_specs(defs)
     abstract_params = pu.abstract_params(defs)
-    if zero2_grads and mesh is not None:
-        _n_data = 1
-        for a in batch_axes:
-            _n_data *= mesh.shape[a]
-        _grad_acc_shardings = jax.tree.map(
-            lambda s: _ns(mesh, s), pu.zero_specs(defs_for_specs, batch_axes, _n_data)
-        )
-    else:
-        _grad_acc_shardings = None
 
     def loss_of(params, batch):
         if cfg.enc_dec:
@@ -125,30 +96,16 @@ def make_train_bundle(
 
             mbs = {k: slice_mb(v) for k, v in batch.items() if hasattr(v, "shape") and v.ndim}
 
-            def shard_acc(t):
-                # ZeRO-2: the fp32 accumulator is data-sharded (XLA lowers
-                # the per-microbatch reduction as a reduce-scatter); the
-                # optimizer consumes it against the equally-sharded m/v.
-                if _grad_acc_shardings is None:
-                    return t
-                return jax.tree.map(
-                    jax.lax.with_sharding_constraint, t, _grad_acc_shardings
-                )
-
             def body(acc, mb):
                 g_acc, loss_acc, metrics_acc = acc
                 (loss, metrics), g = jax.value_and_grad(loss_of, has_aux=True)(
                     params, mb
                 )
-                g_acc = shard_acc(
-                    jax.tree.map(lambda a, b: a + b.astype(jnp.float32), g_acc, g)
-                )
+                g_acc = jax.tree.map(lambda a, b: a + b.astype(jnp.float32), g_acc, g)
                 metrics_acc = jax.tree.map(lambda a, b: a + b, metrics_acc, metrics)
                 return (g_acc, loss_acc + loss, metrics_acc), None
 
-            g0 = shard_acc(
-                jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-            )
+            g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
             mkeys = ["ce", "aux"] + (["mtp_ce"] if cfg.mtp_depth else [])
             m0 = {k: jnp.zeros((), jnp.float32) for k in mkeys}
             from repro.models import flags as _flags
@@ -182,18 +139,14 @@ def make_train_bundle(
             cfg, model, optimizer, step_fn, abstract_params, None, None, None, {}
         )
 
-    bspec = _batch_spec(batch_axes)
     param_sh = jax.tree.map(lambda s: _ns(mesh, s), param_specs)
-    n_data = 1
-    for a in batch_axes:
-        n_data *= mesh.shape[a]
-    zspecs = pu.zero_specs(defs_for_specs, batch_axes, n_data)
+    zspecs = pu.zero_specs(defs, mesh.shape["data"])
     opt_specs = optimizer.state_specs(param_specs, zspecs)
     opt_sh = jax.tree.map(lambda s: _ns(mesh, s), opt_specs)
     batch_sh = {
-        "tokens": _ns(mesh, P(bspec, None)),
-        "labels": _ns(mesh, P(bspec, None)),
-        "frontend_embeds": _ns(mesh, P(bspec, None, None)),
+        "tokens": _ns(mesh, P("data", None)),
+        "labels": _ns(mesh, P("data", None)),
+        "frontend_embeds": _ns(mesh, P("data", None, None)),
     }
     abstract_opt = jax.eval_shape(optimizer.init, abstract_params)
 
@@ -239,12 +192,11 @@ class ServeBundle:
 def make_serve_bundle(
     cfg: ArchConfig,
     mesh: Optional[jax.sharding.Mesh] = None,
-    batch_axes: Tuple[str, ...] = ("data",),
     batch: int = 1,
     max_len: int = 2048,
     q_chunk: int = 1024,
 ) -> ServeBundle:
-    model = build_model(cfg, mesh, batch_axes, q_chunk=q_chunk)
+    model = build_model(cfg, mesh, q_chunk=q_chunk)
     defs = model.param_defs()
     abstract_params = pu.abstract_params(defs)
 
@@ -271,27 +223,18 @@ def make_serve_bundle(
             abstract_cache,
         )
 
-    n_data = 1
-    for a in batch_axes:
-        n_data *= mesh.shape[a]
+    n_data = mesh.shape["data"]
     if cfg.fsdp:
-        p_specs = pu.fsdp_param_specs(defs, batch_axes, n_data)
+        p_specs = pu.fsdp_param_specs(defs, n_data)
     else:
         p_specs = pu.partition_specs(defs)
     param_sh = jax.tree.map(lambda s: _ns(mesh, s), p_specs)
     cache_specs = model.cache_specs()
     if batch % n_data:
-        # batch (e.g. long_500k B=1) cannot shard over the data axes: the
+        # batch (e.g. long_500k B=1) cannot shard over the data axis: the
         # cache stays seq-sharded only.
         def _strip(spec: P) -> P:
-            entries = []
-            for e in tuple(spec):
-                es = e if isinstance(e, tuple) else (e,)
-                if any(a in batch_axes for a in es if a):
-                    entries.append(None)
-                else:
-                    entries.append(e)
-            return P(*entries)
+            return P(*(None if e == "data" else e for e in spec))
 
         cache_specs = jax.tree.map(
             _strip, cache_specs, is_leaf=lambda v: isinstance(v, P)

@@ -125,7 +125,7 @@ _MESH_CHILD = textwrap.dedent(
     pos = jnp.broadcast_to(jnp.arange(256), (2, 256))
 
     def loss(p, x, mesh):
-        o = attn.gqa_forward(p, cfg, x, pos, 128, mesh, ("data",))
+        o = attn.gqa_forward(p, cfg, x, pos, 128, mesh)
         return jnp.sum(o * o), o
 
     out = {}

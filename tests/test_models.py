@@ -59,7 +59,7 @@ def test_moe_sort_matches_onehot(rng):
     x = _arr(rng, 2, 16, cfg.d_model)
     mesh = make_host_mesh(jax.devices()[:1])
     out_sort, aux_sort = jax.jit(
-        lambda p, x: moe_mod.moe_forward(p, cfg, x, mesh, ("data",))
+        lambda p, x: moe_mod.moe_forward(p, cfg, x, mesh)
     )(params, x)
     out_oh, aux_oh = jax.jit(lambda p, x: moe_mod.moe_forward_onehot(p, cfg, x))(
         params, x

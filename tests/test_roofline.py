@@ -1,16 +1,10 @@
-"""Roofline analysis: HLO parsing and term computation."""
+"""Roofline analysis: HLO parsing and model FLOPs."""
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
-from repro.roofline.analysis import (
-    Roofline,
-    _shape_bytes,
-    analyze,
-    parse_collectives,
-)
+from repro.roofline.analysis import _shape_bytes, parse_collectives
 
 
 def test_shape_bytes():
@@ -55,21 +49,25 @@ def test_parse_collectives_async_pairs_not_double_counted():
     assert stats.counts == {"all-reduce": 1}
 
 
-def test_parse_real_sharded_program():
-    """Collectives of a real pjit matmul with conflicting shardings."""
-    if jax.device_count() < 2:
-        pytest.skip("needs >1 device (dryrun covers this path at 512)")
+def test_parse_real_sharded_program(v5e_2x2, no_persistent_cache):
+    """Collectives of a real sharded matmul, compiled for a (data 2, model 2)
+    mesh over a described v5e: contracting over the model-sharded dim
+    all-reduces the partial products across the model axis."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
 
+    from repro.launch.mesh import make_host_mesh
 
-def test_analyze_terms():
-    cost = {"flops": 197e12, "bytes accessed": 819e9}
-    hlo = "  %p0 = bf16[1024,1024]{1,0} parameter(0)\n  %ar = bf16[1024,1024]{1,0} all-reduce(%p0)\n"
-    r = analyze(cost, hlo, model_flops_global=197e12 * 256, num_chips=256)
-    assert abs(r.compute_s - 1.0) < 1e-9
-    assert abs(r.memory_s - 1.0) < 1e-9
-    assert r.collective_bytes == 1024 * 1024 * 2
-    assert r.bottleneck in ("compute", "memory")
-    assert abs(r.useful_ratio - 1.0) < 1e-9
+    mesh = make_host_mesh(v5e_2x2.devices)
+    x = jax.ShapeDtypeStruct((256, 512), jnp.bfloat16, sharding=NamedSharding(mesh, P("data", "model")))
+    w = jax.ShapeDtypeStruct((512, 384), jnp.bfloat16, sharding=NamedSharding(mesh, P("model", None)))
+    out = NamedSharding(mesh, P("data", None))
+    compiled = jax.jit(jnp.matmul, out_shardings=out).lower(x, w).compile()
+    stats = parse_collectives(compiled.as_text())
+    assert stats.counts == {"all-reduce": 1}
+    # each device all-reduces its (128, 384) partial product
+    assert stats.operand_bytes["all-reduce"] == 128 * 384 * 2
+    assert stats.wire_bytes == 2 * 128 * 384 * 2
 
 
 def test_model_flops_for_cell():

@@ -4,18 +4,15 @@ a 4096 sliding window) and ``mamba2-370m`` SSD (32 heads x 64, state 128,
 chunk 256).  Interpret mode (``test_kernels.py``) cannot see the TPU
 compiler's tiling rules; these compiles can, with no chip attached.
 
-The topology is described inside a fixture, never at import: only one
-process at a time may load the TPU library, and every test worker imports
-this file.
+The topology is described by the ``v5e_2x2`` fixture (``conftest.py``).
 """
 
-import os
 import re
 
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, SingleDeviceSharding
 
 from repro.kernels import ops, splash_attention
 from repro.models import common
@@ -24,29 +21,8 @@ BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
-
-    try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # no TPU compiler in this installation
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
-
-
-@pytest.fixture(scope="module")
-def no_persistent_cache():
-    # a compile for a described chip is written to the persistent cache but
-    # cannot be read back without the chip
-    from jax.experimental.compilation_cache import compilation_cache
-
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _flash_train(q, k, v):
@@ -142,3 +118,69 @@ def test_ssd_chunked_train_cost_for_v5e(one_chip, no_persistent_cache):
     cost = cost[0] if isinstance(cost, list) else cost
     assert cost["bytes accessed"] < 4.0e9
     assert compiled.memory_analysis().temp_size_in_bytes < 400e6
+
+
+def _tiny_danube():
+    from repro.configs import get_config, smoke_config
+
+    # 4 query / 2 kv heads split over model 2; 128 positions take the kernel
+    return smoke_config(get_config("h2o-danube-1.8b"))
+
+
+def _sharded(tree, shardings):
+    return jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), tree, shardings
+    )
+
+
+def _assert_shardings(got, mesh, specs):
+    want = jax.tree.map(lambda s: NamedSharding(mesh, s), specs)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.spec == w.spec, (g.spec, w.spec)
+
+
+@pytest.mark.parametrize("step", ["train", "decode"])
+def test_host_mesh_bundle_compiles_for_v5e(step, v5e_2x2, no_persistent_cache):
+    """Tiny danube's train step and decode step compile over the host mesh
+    of data 2 x model 2, the layout ``mesh4.danube`` runs: the parameters
+    keep the specs of their definitions and the optimizer state its ZeRO
+    specs, in and out, and the train step all-reduces its gradients."""
+    from repro.launch.mesh import make_host_mesh
+    from repro.models import params as pu
+    from repro.roofline.analysis import parse_collectives
+    from repro.train.steps import make_serve_bundle, make_train_bundle
+
+    cfg = _tiny_danube()
+    mesh = make_host_mesh(v5e_2x2.devices)
+    assert dict(mesh.shape) == {"data": 2, "model": 2}
+    if step == "train":
+        bundle = make_train_bundle(cfg, mesh, q_chunk=64)
+        defs = bundle.model.param_defs()
+        param_specs = pu.partition_specs(defs)
+        opt_specs = bundle.optimizer.state_specs(param_specs, pu.zero_specs(defs, 2))
+        tokens = jax.ShapeDtypeStruct((4, 128), I32, sharding=bundle.batch_shardings["tokens"])
+        compiled = bundle.step_fn.lower(
+            _sharded(bundle.abstract_params, bundle.param_shardings),
+            _sharded(bundle.abstract_opt, bundle.opt_shardings),
+            {"tokens": tokens, "labels": tokens},
+        ).compile()
+        (p_in, o_in, _), _ = compiled.input_shardings
+        p_out, o_out, _ = compiled.output_shardings
+        for got, specs in ((p_in, param_specs), (p_out, param_specs), (o_in, opt_specs), (o_out, opt_specs)):
+            _assert_shardings(got, mesh, specs)
+        assert parse_collectives(compiled.as_text()).counts.get("all-reduce", 0) > 0
+    else:
+        bundle = make_serve_bundle(cfg, mesh, batch=4, max_len=128, q_chunk=64)
+        param_specs = pu.partition_specs(bundle.model.param_defs())
+        compiled = bundle.decode_fn.lower(
+            bundle.abstract_params,
+            _sharded(bundle.abstract_cache, bundle.cache_shardings),
+            jax.ShapeDtypeStruct((4, 1), I32),
+            jax.ShapeDtypeStruct((), I32),
+        ).compile()
+        (p_in, c_in, _, _), _ = compiled.input_shardings
+        _, c_out = compiled.output_shardings
+        _assert_shardings(p_in, mesh, param_specs)
+        for got in (c_in, c_out):
+            _assert_shardings(got, mesh, bundle.model.cache_specs())
